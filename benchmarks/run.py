@@ -8,6 +8,9 @@ Prints ``name,us_per_call,derived`` CSV. Sections:
   kernels — op-backend micro-benchmarks + bit-exactness
   serve   — per-slot vs batched vs paged serve engines (also writes
             BENCH_serve.json with the paged-vs-dense capacity comparison)
+  serve_mesh — the mesh capacity sweep (``serve_bench.py --mesh-scaling``)
+            in a process of its own with 8 devices, started before this
+            process touches JAX: a chip belongs to one process at a time
 
 ``--smoke`` only imports every benchmark module (CI import check: catches
 broken imports / renamed APIs without paying the full benchmark runtime).
@@ -169,9 +172,31 @@ def smoke() -> None:
         sys.exit(1)
 
 
+def _serve_mesh() -> bool:
+    """Run the mesh sweep in a child process with 8 devices (forced host
+    devices on the CPU; on an accelerator, the chips it has). Called
+    before any section imports JAX, so the child can hold the devices."""
+    import subprocess
+
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(_ROOT, "src"), env.get("PYTHONPATH", ""))
+        if p)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "benchmarks", "serve_bench.py"),
+         "--mesh-scaling"], env=env, capture_output=True, text=True)
+    sys.stdout.write(proc.stdout)
+    if proc.returncode:
+        print(f"serve_mesh_CLAIM_FAILED,0.0,exit {proc.returncode}")
+        sys.stderr.write(proc.stderr[-4000:])
+    return proc.returncode == 0
+
+
 def main() -> None:
-    failures = 0
     print("name,us_per_call,derived")
+    failures = 0 if _serve_mesh() else 1
     for label, mod in SECTIONS:
         try:
             m = importlib.import_module(mod)

@@ -42,11 +42,12 @@ Claims validated:
     iteration wall) drops ≥ 4x vs monolithic admission at ≥ 0.9x the
     aggregate tokens/s, token-identically;
 
-  * **mesh scaling** (ISSUE 7 shard_map serving): at a fixed per-device
-    block budget, the mesh-sharded pool's aggregate capacity scales with
-    device count — ≥ 1.8x the concurrent requests at 2 devices and
-    monotone to 8 — while outputs stay token-identical to the
-    single-device engine (heads mode slices the KV-head axis, blocks
+  * **mesh scaling** (ISSUE 7 shard_map serving; ``--mesh-scaling``, a
+    run of its own in the process that holds the devices): at a fixed
+    per-device block budget, the mesh-sharded pool's aggregate capacity
+    scales with device count — ≥ 1.8x the concurrent requests at 2
+    devices and monotone to 8 — while outputs stay token-identical to
+    the single-device engine (heads mode slices the KV-head axis, blocks
     mode partitions the pool; the sweep crosses both);
 
   * **QoS traffic classes** (ISSUE 5 scheduler/engine split): with every
@@ -290,110 +291,128 @@ MESH_BUDGET = 13       # per-device block budget (incl. the trash block)
 MESH_SLOTS = 48
 MESH_NEW = 8
 
-# The sweep needs a multi-device runtime, and the host device count is
-# fixed at jax import — so the parent (which already imported jax on
-# however many devices it was given) runs the sweep in a child process
-# with 8 forced host devices. The child prints one JSON line.
-_MESH_CHILD = r"""
-import json, time
-import numpy as np
-import jax
 
-from repro import configs
-from repro.models import registry, schema as schema_lib
-from repro.serve import EngineConfig, LLMEngine
-from repro.launch.mesh import make_serve_mesh
+def _mesh_scaling(devices):
+    """Mesh capacity sweep at a fixed per-device block budget, in this
+    process, over the first ``n`` of its devices for each ``n`` in
+    ``devices``.
 
-DEVICES, BUDGET, SLOTS, NEW, BLOCK_LEN = {params}
-
-cfg = configs.smoke_config("phi3-mini-3.8b")
-arch = registry.build(cfg)
-params = schema_lib.init_params(arch.schema(), jax.random.key(0))
-
-
-def workload():
-    # short requests: 4..8-token prompts + NEW decoded tokens stay inside
-    # 2 blocks each, so capacity = usable_blocks // 2 per device
-    rng = np.random.default_rng(11)
-    return [rng.integers(0, cfg.vocab, size=int(rng.integers(4, 9))
-                         ).astype(np.int32) for _ in range(SLOTS)]
-
-
-entries, base = [], None
-for n in DEVICES:
-    mesh = make_serve_mesh(n)
-    # num_blocks = BUDGET * n holds per-device bytes fixed in BOTH modes:
-    # heads mode stores all blocks but a 1/n head-slice of each; blocks
-    # mode stores full-head blocks but only 1/n of them
-    ec = EngineConfig(slots=SLOTS, max_len=64, block_len=BLOCK_LEN,
-                     backend="paged", num_blocks=BUDGET * n,
-                     admit_batch=SLOTS)
-    eng = LLMEngine(arch, params, ec, mesh=mesh)
-    for rid, p in enumerate(workload()):
-        eng.add_request(p, max_new_tokens=NEW, rid=rid)
-    out = {r.rid: list(r.output) for r in eng.run_until_drained()}
-    if base is None:
-        base = out
-    assert out == base, f"mesh={n} diverged from single-device output"
-    # timed second drain: every trace is warm, so this measures serving
-    for rid, p in enumerate(workload()):
-        eng.add_request(p, max_new_tokens=NEW, rid=10_000 + rid)
-    t0 = time.perf_counter()
-    eng.run_until_drained()
-    wall = time.perf_counter() - t0
-    m = eng.metrics()
-    entries.append({
-        "devices": n,
-        "kv_shard": eng.kv_mode,
-        "num_blocks": BUDGET * n,
-        "pool_bytes_per_device": max(
-            v for k, v in m.items() if k.startswith("pool_bytes_dev")),
-        "pool_blocks_total": m["pool_blocks_total"],
-        "concurrent": eng.max_concurrent,
-        "tokens_per_s": SLOTS * NEW / wall,
-    })
-print(json.dumps({"entries": entries}))
-"""
-
-
-def _mesh_scaling():
-    """Mesh capacity sweep at a fixed per-device block budget.
-
-    One child process with 8 forced host devices serves the same
-    short-request workload on 1/2/4/8-device meshes, each mesh given
+    Every mesh serves the same short-request workload with
     ``MESH_BUDGET`` blocks of per-device pool memory; reports peak
     concurrency and warm tokens/s per mesh. Outputs are asserted
-    token-identical across device counts inside the child."""
-    import os
-    import subprocess
-    import sys
+    token-identical across device counts."""
+    import jax
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    child = _MESH_CHILD.replace("{params}", repr(
-        (MESH_DEVICES, MESH_BUDGET, MESH_SLOTS, MESH_NEW, BLOCK_LEN)))
-    proc = subprocess.run([sys.executable, "-c", child], env=env,
-                          capture_output=True, text=True, timeout=3600)
-    assert proc.returncode == 0, (
-        f"mesh scaling child failed:\n{proc.stderr[-4000:]}")
-    entries = json.loads(proc.stdout.strip().splitlines()[-1])["entries"]
+    from repro import configs
+    from repro.launch.mesh import make_serve_mesh
+    from repro.models import registry, schema as schema_lib
+    from repro.serve import EngineConfig, LLMEngine
+
+    cfg = configs.smoke_config("phi3-mini-3.8b")
+    arch = registry.build(cfg)
+    params = schema_lib.init_params(arch.schema(), jax.random.key(0))
+
+    def workload():
+        # short requests: 4..8-token prompts + MESH_NEW decoded tokens
+        # stay inside 2 blocks each, so capacity = usable_blocks // 2 per
+        # device
+        rng = np.random.default_rng(11)
+        return [rng.integers(0, cfg.vocab, size=int(rng.integers(4, 9))
+                             ).astype(np.int32) for _ in range(MESH_SLOTS)]
+
+    entries, base = [], None
+    for n in devices:
+        mesh = make_serve_mesh(n)
+        # num_blocks = MESH_BUDGET * n holds per-device bytes fixed in BOTH
+        # modes: heads mode stores all blocks but a 1/n head-slice of each;
+        # blocks mode stores full-head blocks but only 1/n of them
+        ec = EngineConfig(slots=MESH_SLOTS, max_len=64, block_len=BLOCK_LEN,
+                          backend="paged", num_blocks=MESH_BUDGET * n,
+                          admit_batch=MESH_SLOTS)
+        eng = LLMEngine(arch, params, ec, mesh=mesh)
+        for rid, p in enumerate(workload()):
+            eng.add_request(p, max_new_tokens=MESH_NEW, rid=rid)
+        out = {r.rid: list(r.output) for r in eng.run_until_drained()}
+        if base is None:
+            base = out
+        assert out == base, f"mesh={n} diverged from single-device output"
+        # timed second drain: every trace is warm, so this measures serving
+        for rid, p in enumerate(workload()):
+            eng.add_request(p, max_new_tokens=MESH_NEW, rid=10_000 + rid)
+        t0 = time.perf_counter()
+        eng.run_until_drained()
+        wall = time.perf_counter() - t0
+        m = eng.metrics()
+        entries.append({
+            "devices": n,
+            "kv_shard": eng.kv_mode,
+            "num_blocks": MESH_BUDGET * n,
+            "pool_bytes_per_device": max(
+                v for k, v in m.items() if k.startswith("pool_bytes_dev")),
+            "pool_blocks_total": m["pool_blocks_total"],
+            "concurrent": eng.max_concurrent,
+            "tokens_per_s": MESH_SLOTS * MESH_NEW / wall,
+        })
     cap = {e["devices"]: e["concurrent"] for e in entries}
     return {
         "arch": "phi3-mini-3.8b",
         "block_len": BLOCK_LEN,
         "budget_blocks_per_device": MESH_BUDGET,
         "slots": MESH_SLOTS,
+        "device": jax.devices()[0].platform,
         "entries": entries,
         "capacity_ratio_2dev": cap[2] / cap[1],
-        "capacity_ratio_8dev": cap[8] / cap[1],
+        "capacity_ratio_max": cap[max(cap)] / cap[1],
         "token_identical_across_meshes": True,
     }
+
+
+def mesh_main(csv: bool = True):
+    """``--mesh-scaling``: the mesh sweep alone, over the devices this
+    process holds (on the CPU, start it under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``). Writes its
+    entry into ``BENCH_serve.json`` under ``paged.mesh_scaling``."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    devices = tuple(n for n in MESH_DEVICES if n <= len(jax.devices()))
+    if 2 not in devices:
+        raise SystemExit(
+            f"--mesh-scaling needs at least 2 devices, this process has "
+            f"{len(jax.devices())}; on the CPU run it under XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8")
+    mesh_scaling = _mesh_scaling(devices)
+    caps = {e["devices"]: e["concurrent"] for e in mesh_scaling["entries"]}
+    row = ("serve_paged_mesh_scaling", 0.0,
+           "concurrent=" + "/".join(f"{caps[n]}@{n}dev" for n in devices)
+           + f"|2dev_ratio={mesh_scaling['capacity_ratio_2dev']:.2f}x "
+           f"(claim: >=1.8x)|"
+           f"{max(devices)}dev_ratio="
+           f"{mesh_scaling['capacity_ratio_max']:.2f}x|"
+           f"budget={MESH_BUDGET} blocks/device|identical=yes")
+    if csv:
+        print(f"{row[0]},{row[1]:.1f},{row[2]}")
+    try:
+        with open("BENCH_serve.json") as f:
+            bench = json.load(f)
+    except FileNotFoundError:
+        bench = {}
+    bench.setdefault("paged", {})["mesh_scaling"] = mesh_scaling
+    with open("BENCH_serve.json", "w") as f:
+        json.dump(bench, f, indent=2)
+    assert mesh_scaling["capacity_ratio_2dev"] >= 1.8, (
+        f"2-device mesh admitted only "
+        f"{mesh_scaling['capacity_ratio_2dev']:.2f}x the single-device "
+        f"concurrency at an equal per-device pool budget (claim: >=1.8x)")
+    for lo, hi in zip(devices, devices[1:]):
+        assert caps[hi] >= caps[lo], (
+            f"mesh capacity not monotone: {caps[lo]} concurrent at "
+            f"{lo} devices but {caps[hi]} at {hi}")
+    assert caps[max(devices)] > caps[1], (
+        f"mesh capacity flat from 1->{max(devices)} devices")
+    return [row]
 
 
 QOS_SLOTS = 4
@@ -757,12 +776,14 @@ def main(csv: bool = True):
     import jax
 
     from repro import configs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import registry, schema as schema_lib
     from repro.serve.engine import (
         BatchedServeEngine, EngineConfig, PagedServeEngine, ServeEngine,
         metrics,
     )
 
+    enable_compile_cache()
     cfg = configs.smoke_config("phi3-mini-3.8b")
     arch = registry.build(cfg)
     params = schema_lib.init_params(arch.schema(), jax.random.key(0))
@@ -983,21 +1004,6 @@ def main(csv: bool = True):
         f"{speculative['iterations_on']}|identical=yes",
     ))
 
-    # mesh scaling (child process, 8 forced host devices): fixed
-    # per-device block budget, capacity + tokens/s at 1/2/4/8 devices
-    mesh_scaling = _mesh_scaling()
-    mesh_caps = {e["devices"]: e["concurrent"]
-                 for e in mesh_scaling["entries"]}
-    rows.append((
-        "serve_paged_mesh_scaling", 0.0,
-        "concurrent=" + "/".join(
-            f"{mesh_caps[n]}@{n}dev" for n in MESH_DEVICES)
-        + f"|2dev_ratio={mesh_scaling['capacity_ratio_2dev']:.2f}x "
-        f"(claim: >=1.8x)|"
-        f"8dev_ratio={mesh_scaling['capacity_ratio_8dev']:.2f}x|"
-        f"budget={MESH_BUDGET} blocks/device|identical=yes",
-    ))
-
     # QoS traffic classes: rt-vs-be TTFT under full be contention, FCFS
     # vs the two-class QoS scheduler (same workload, same backend)
     qos_classes = _qos_contention(arch, params, cfg)
@@ -1019,6 +1025,12 @@ def main(csv: bool = True):
         for r in rows:
             print(f"{r[0]},{r[1]:.1f},{r[2]}")
 
+    try:
+        with open("BENCH_serve.json") as f:
+            previous_mesh = json.load(f).get("paged", {}).get(
+                "mesh_scaling")
+    except FileNotFoundError:
+        previous_mesh = None
     with open("BENCH_serve.json", "w") as f:
         json.dump({
             "name": "serve_batched",
@@ -1039,7 +1051,10 @@ def main(csv: bool = True):
                 "prefix_cache": prefix_cache,
                 "chunked_prefill": chunked_prefill,
                 "speculative": speculative,
-                "mesh_scaling": mesh_scaling,
+                # written by --mesh-scaling, which runs in a process of
+                # its own; kept across this run's rewrite
+                **({"mesh_scaling": previous_mesh}
+                   if previous_mesh is not None else {}),
             },
             "qos_classes": qos_classes,
         }, f, indent=2)
@@ -1078,15 +1093,6 @@ def main(csv: bool = True):
         f"speculative decoding won only "
         f"{speculative['tokens_per_s_ratio']:.2f}x tokens/s per slot over "
         f"plain paged decode on the repetitive workload (claim: >=1.3x)")
-    assert mesh_scaling["capacity_ratio_2dev"] >= 1.8, (
-        f"2-device mesh admitted only "
-        f"{mesh_scaling['capacity_ratio_2dev']:.2f}x the single-device "
-        f"concurrency at an equal per-device pool budget (claim: >=1.8x)")
-    for lo, hi in zip(MESH_DEVICES, MESH_DEVICES[1:]):
-        assert mesh_caps[hi] >= mesh_caps[lo], (
-            f"mesh capacity not monotone: {mesh_caps[lo]} concurrent at "
-            f"{lo} devices but {mesh_caps[hi]} at {hi}")
-    assert mesh_caps[8] > mesh_caps[1], "mesh capacity flat from 1->8 devices"
     assert qos_classes["rt_p99_improvement"] >= 4.0, (
         f"QoS scheduler lowered rt p99 TTFT only "
         f"{qos_classes['rt_p99_improvement']:.2f}x vs FCFS (claim: >=4x)")
@@ -1098,4 +1104,13 @@ def main(csv: bool = True):
 
 
 if __name__ == "__main__":
-    main()
+    import argparse
+
+    ap = argparse.ArgumentParser(description="serve-engine benchmark")
+    ap.add_argument("--mesh-scaling", action="store_true",
+                    help="run only the mesh capacity sweep, over the "
+                         "devices this process holds")
+    if ap.parse_args().mesh_scaling:
+        mesh_main()
+    else:
+        main()

@@ -6,8 +6,8 @@ of defect unrepresentable.  Kernel bodies are the functions handed to
 ``pl.pallas_call`` (directly, through ``functools.partial``, or via an
 assigned alias); ref parameters are the kernel's positional arguments.
 
-  PI1  ``pl.store(ref, (...idx...), v)`` / ``pl.load(ref, (...idx...))``:
-       every index element must be static — an int literal, ``slice``,
+  PI1  ``pl.load(ref, (...idx...))``, and the same index tuple in the
+       ``pl.store`` call of JAX before 0.9: every index element must be static — an int literal, ``slice``,
        ``Ellipsis``/``None`` — or an explicit ``pl.dslice``/``pl.ds``.
        A dynamic element (a loop variable, ``program_id`` arithmetic)
        indexes relative to the block mapping with *element* granularity

@@ -52,7 +52,7 @@ _TRACER_NAMES = {
     "jax.jit",
     "jax.pmap",
     "jax.experimental.shard_map.shard_map",
-    "repro.core.compat.shard_map",
+    "jax.shard_map",
 }
 _PALLAS_CALL = {"jax.experimental.pallas.pallas_call"}
 _PARTIAL_NAMES = {"functools.partial"}

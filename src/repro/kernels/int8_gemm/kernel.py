@@ -36,6 +36,8 @@ PAPER_BLOCK = (256, 512, 128)  # (bm, bk, bn)
 # Beyond-paper tuned block (see §Perf): square-ish tiles maximize MXU
 # utilization and VMEM reuse on v5e.
 TUNED_BLOCK = (512, 512, 512)
+# int8 second-minor tile: a row block is a multiple of this
+INT8_ROW_TILE = 32
 
 
 def _gemm_kernel(x_ref, w_ref, b_ref, m_ref, s_ref, o_ref, acc_ref,
@@ -81,15 +83,24 @@ def int8_gemm_pallas(
     act_scales: Optional[tuple] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Blocked W8A8 GEMM → int8, requant fused. M, K, N must divide blocks."""
+    """Blocked W8A8 GEMM → int8, requant fused.
+
+    K and N must divide their blocks. Any M is served: the row block is
+    fitted to M (rounded up to the int8 sublane tile of 32) and the rows
+    are zero-padded to a multiple of it, so a decode batch of a few slots
+    runs one small row block instead of a 256-row one."""
     m_dim, k_dim = x_q.shape
     _, n_dim = w_q.shape
     bm, bk, bn = block
-    bm, bk, bn = min(bm, m_dim), min(bk, k_dim), min(bn, n_dim)
-    if m_dim % bm or k_dim % bk or n_dim % bn:
+    bm = min(bm, -(-m_dim // INT8_ROW_TILE) * INT8_ROW_TILE)
+    bk, bn = min(bk, k_dim), min(bn, n_dim)
+    if k_dim % bk or n_dim % bn:
         raise ValueError(f"shapes {(m_dim, k_dim, n_dim)} not divisible by block {(bm, bk, bn)}")
+    m_pad = -(-m_dim // bm) * bm
+    if m_pad != m_dim:
+        x_q = jnp.pad(x_q, ((0, m_pad - m_dim), (0, 0)))
     nk = k_dim // bk
-    grid = (m_dim // bm, n_dim // bn, nk)
+    grid = (m_pad // bm, n_dim // bn, nk)
 
     kernel = functools.partial(
         _gemm_kernel, nk=nk, activation=activation, act_scales=act_scales
@@ -105,7 +116,7 @@ def int8_gemm_pallas(
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_dim, n_dim), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n_dim), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(
@@ -114,4 +125,4 @@ def int8_gemm_pallas(
         bias.reshape(1, n_dim),
         mult.reshape(1, n_dim),
         shift.reshape(1, n_dim),
-    )
+    )[:m_dim]

@@ -20,10 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quant
+from repro.kernels import resolve_backend
 from repro.kernels.int8_gemm.kernel import PAPER_BLOCK, int8_gemm_pallas
 from repro.kernels.int8_gemm.ref import int8_gemm_ref
-
-DEFAULT_BACKEND = "xla"
 
 
 @jax.tree_util.register_dataclass
@@ -51,10 +50,11 @@ def int8_gemm(
     *,
     activation: str = "none",
     act_scales: Optional[tuple] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     block=PAPER_BLOCK,
 ) -> jax.Array:
     """[..., K] int8 → [..., N] int8 quantized linear."""
+    backend = resolve_backend(backend)
     lead = x_q.shape[:-1]
     x2 = x_q.reshape(-1, x_q.shape[-1])
     if backend in ("pallas", "interpret"):

@@ -9,13 +9,14 @@ serving engine on CPU).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_backend
 from repro.kernels.ita_attention.kernel import ita_attention_pallas
 from repro.kernels.ita_attention.ref import ita_attention_ref
-
-DEFAULT_BACKEND = "xla"
 
 
 def ita_attention(
@@ -30,8 +31,9 @@ def ita_attention(
     logit_amax: float = 10.0,
     block_q: int = 128,
     block_kv: int = 128,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
+    backend = resolve_backend(backend)
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     if hq % hkv:

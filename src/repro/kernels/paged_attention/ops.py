@@ -13,14 +13,15 @@ int8 blocks with per-block scales (the serving layout fills the scales with
 the static ``attn.KV_SCALE`` calibration; the arrays exist so per-block
 calibration can land without a layout change).
 
-Backends (set ``REPRO_PAGED_ATTN_BACKEND`` to override the default):
+Backends (``backend=None`` picks from the platform: ``pallas`` on a TPU,
+``xla`` elsewhere — see ``repro.kernels.resolve_backend``):
   * ``pallas``    — TPU kernel; scalar-prefetched block table (plus, for
     int8, the per-block scale vectors) drives the BlockSpec index maps so
     pool blocks are DMA'd on demand. Int8 pools move half the bytes and
     dequantize on the fly into f32 flash accumulators.
   * ``interpret`` — same kernels through the Pallas interpreter (CPU/CI).
-  * ``xla``       — gather-then-dense oracle (``ref.py``); the default on
-    this container. For int8 pools this is the ITA integer pipeline over
+  * ``xla``       — gather-then-dense oracle (``ref.py``); the default off
+    the TPU. For int8 pools this is the ITA integer pipeline over
     the gathered blocks — bit-identical to the dense int8 serving
     reference, which is what the paged-vs-dense token-identity matrix
     anchors on.
@@ -45,7 +46,6 @@ pins the bit-identity of this arrangement.
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.annotations import hot_path
+from repro.kernels import resolve_backend
 from repro.kernels.paged_attention.kernel import (
     paged_attention_int8_pallas, paged_attention_pallas,
     paged_attention_verify_int8_pallas, paged_attention_verify_pallas,
@@ -61,8 +62,6 @@ from repro.kernels.paged_attention.ref import (
     paged_attention_int8_ref, paged_attention_ref,
     paged_attention_verify_int8_ref, paged_attention_verify_ref,
 )
-
-DEFAULT_BACKEND = os.environ.get("REPRO_PAGED_ATTN_BACKEND", "xla")
 
 # backends implementing decode over float block pools
 BACKENDS = ("pallas", "interpret", "xla")
@@ -80,8 +79,9 @@ def paged_attention(
     *,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
+    backend = resolve_backend(backend)
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(
             f"query heads {q.shape[1]} not a multiple of kv heads "
@@ -108,7 +108,7 @@ def paged_attention_int8(
     v_scale: Optional[jax.Array] = None,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """Decode attention over int8 block pools (see module docstring).
 
@@ -120,6 +120,7 @@ def paged_attention_int8(
     calibration (non-uniform scale arrays) is honored by the ``pallas`` /
     ``interpret`` kernel and the dequant oracle.
     """
+    backend = resolve_backend(backend)
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(
             f"query heads {q.shape[1]} not a multiple of kv heads "
@@ -173,7 +174,7 @@ def paged_attention_verify(
     *,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """Small-q verify attention for speculative decoding.
 
@@ -184,6 +185,7 @@ def paged_attention_verify(
     matches ``paged_attention`` bit-for-bit, which is what keeps the
     speculative engine token-identical to the plain one.
     """
+    backend = resolve_backend(backend)
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(
             f"query heads {q.shape[1]} not a multiple of kv heads "
@@ -210,12 +212,13 @@ def paged_attention_verify_int8(
     v_scale: Optional[jax.Array] = None,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """Int8 small-q verify attention (same numerics split as decode:
     ``xla`` is the exact multi-q ITA oracle, ``pallas``/``interpret`` the
     fused dequant kernel contracted to
     ``ref.paged_attention_verify_int8_dequant_ref``)."""
+    backend = resolve_backend(backend)
     if q.shape[1] % k_pool.shape[1]:
         raise ValueError(
             f"query heads {q.shape[1]} not a multiple of kv heads "

@@ -362,7 +362,7 @@ def paged_attention_sharded_oracle(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
     nshard = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     hkv = k_pool.shape[1]
@@ -379,5 +379,5 @@ def paged_attention_sharded_oracle(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis), P(), P(),
                   P()),
-        out_specs=P(None, axis), check_rep=False)
+        out_specs=P(None, axis), check_vma=False)
     return fn(q, k_pool, v_pool, block_table, lens, start)

@@ -41,8 +41,8 @@ def _rglru_kernel(loga_ref, u_ref, o_ref, h_ref, *, chunk: int):
 
     def step(t, h):
         h = a[t] * h + bu[t]
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[None, None].astype(o_ref.dtype))
+        o_ref[pl.ds(0, 1), pl.ds(t, 1), :] = h[None, None].astype(
+            o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])

@@ -6,13 +6,14 @@ lowers to efficient fused loops and that shards cleanly for the dry-run.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_backend
 from repro.kernels.rglru.kernel import rglru_pallas
 from repro.kernels.rglru.ref import rglru_decode_step, rglru_ref  # noqa: F401
-
-DEFAULT_BACKEND = "xla"
 
 
 @jax.jit
@@ -36,8 +37,9 @@ def rglru(
     u: jax.Array,
     *,
     chunk: int = 256,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
+    backend = resolve_backend(backend)
     if backend in ("pallas", "interpret"):
         return rglru_pallas(log_a, u, chunk=chunk,
                             interpret=backend == "interpret")

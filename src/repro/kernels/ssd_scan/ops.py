@@ -8,14 +8,14 @@ FLOPs to the Pallas kernel, so the dry-run roofline is representative.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_backend
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.kernels.ssd_scan.ref import ssd_decode_step, ssd_scan_ref  # noqa: F401
-
-DEFAULT_BACKEND = "xla"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "return_state"))
@@ -72,9 +72,10 @@ def ssd_scan(
     c_mat: jax.Array,
     *,
     chunk: int = 128,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     return_state: bool = False,
 ):
+    backend = resolve_backend(backend)
     chunk = min(chunk, x.shape[2])
     s = x.shape[2]
     pad = (-s) % chunk

@@ -19,7 +19,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = math.prod(shape)
     if len(jax.devices()) == n:
-        return jax.make_mesh(shape, axes)
+        # shardings here are placed by hand (NamedSharding + shard_map),
+        # so the axes are Auto, not make_mesh's Explicit default
+        return jax.make_mesh(shape, axes, axis_types=(
+            jax.sharding.AxisType.Auto,) * len(axes))
     if len(jax.devices()) < n:
         raise RuntimeError(
             f"need {n} devices for {shape} mesh, have {len(jax.devices())} — "

@@ -11,6 +11,11 @@ Constructs the one serve front-end, ``repro.serve.LLMEngine``, from a
 
 ``--engine batched|paged|reference`` is kept as a deprecated alias for
 ``--backend``.
+
+:func:`build_engine` is the construction both this launcher and
+``chip_smoke.py`` use: seeded random weights in the config's dtype and,
+on a device that reports its memory, a paged pool sized to what the
+weights leave (``PagedBackend`` fits it).
 """
 
 from __future__ import annotations
@@ -26,6 +31,29 @@ from repro.serve.config import (
 )
 
 _ENGINE_NAMES = ("batched", "paged", "reference")
+
+
+def build_engine(model, ec, *, mesh=None) -> "LLMEngine":
+    """``LLMEngine`` for ``model`` with random weights from seed 0 in the
+    config's own dtype, replicated over ``mesh`` when one is given. With
+    the paged backend and ``ec.num_blocks=None`` the backend fits the
+    pool to the device's memory (``PagedBackend.pool_sizing``)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.models import registry, schema as schema_lib
+    from repro.serve import LLMEngine
+
+    arch = registry.build(model)
+    if ec.backend not in arch.serve_backends:
+        raise ValueError(
+            f"backend {ec.backend} unsupported for {model.name} "
+            f"(family {model.family}): supported = "
+            f"{', '.join(arch.serve_backends)}")
+    rep = None if mesh is None else NamedSharding(mesh, PartitionSpec())
+    params = schema_lib.init_params(arch.schema(), jax.random.key(0),
+                                    dtype=model.compute_dtype, sharding=rep)
+    return LLMEngine(arch, params, ec, mesh=mesh)
 
 
 def main():
@@ -98,22 +126,14 @@ def main():
                          "picks heads when the head count divides --mesh")
     args = ap.parse_args()
 
-    import jax
-
     from repro import configs
-    from repro.models import registry, schema as schema_lib
-    from repro.serve import EngineConfig, LLMEngine, metrics
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve import EngineConfig, metrics
 
+    enable_compile_cache()
     backend = canonical_backend(args.backend or args.engine or "batched")
     model = (configs.smoke_config(args.arch) if args.smoke
              else configs.get_config(args.arch))
-    arch = registry.build(model)
-    if backend not in arch.serve_backends:
-        raise SystemExit(
-            f"--backend {backend} unsupported for {model.name} "
-            f"(family {model.family}): supported = "
-            f"{', '.join(arch.serve_backends)}")
-    params = schema_lib.init_params(arch.schema(), jax.random.key(0))
     ec = EngineConfig(slots=args.slots, max_len=args.max_len,
                       admit_window=args.admit_window,
                       admit_batch=args.admit_batch,
@@ -132,7 +152,16 @@ def main():
     if args.mesh:
         from repro.launch.mesh import make_serve_mesh
         mesh = make_serve_mesh(args.mesh)
-    engine = LLMEngine(arch, params, ec, mesh=mesh)
+    try:
+        engine = build_engine(model, ec, mesh=mesh)
+    except ValueError as e:
+        raise SystemExit(str(e))
+    sizing = getattr(engine.backend, "pool_sizing", None)
+    if sizing:
+        print(f"pool: {sizing['num_blocks']} blocks of "
+              f"{sizing['block_bytes']} B per device (memory fits "
+              f"{sizing['fit_blocks']}, the config addresses "
+              f"{sizing['want_blocks']})")
     if mesh is not None:
         em = engine.metrics()
         per_dev = {k: v for k, v in em.items()

@@ -226,7 +226,7 @@ def int8_attention(
     k_scale: float = 4.0 / 127,
     v_scale: float = 4.0 / 127,
     out_scale: float = 4.0 / 127,
-    backend: str = "xla",
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """Paper-faithful INT8 attention (float in/out; quantized inside).
 

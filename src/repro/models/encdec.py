@@ -16,7 +16,7 @@ paged layout); weights and the fixed-size cross K/V arena stay float.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -343,7 +343,8 @@ def paged_prefill(params, tokens, cfg: ModelConfig, cache, slot, block_ids,
 
 
 def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, embeds=None, attn_backend: str = "xla",
+                      qparams=None, embeds=None,
+                      attn_backend: Optional[str] = None,
                       shard=None):
     """One decode step with paged self-attention KV (cross K/V stays dense).
 
@@ -412,7 +413,8 @@ def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
 
 
 def paged_verify_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, embeds=None, attn_backend: str = "xla"):
+                      qparams=None, embeds=None,
+                      attn_backend: Optional[str] = None):
     """Speculative-decode verify step (see ``transformer.paged_verify_step``
     for the token/position contract): ``tokens`` [slots, Q] scores all
     Q = spec_tokens + 1 positions per slot in one dispatch. Self-attention

@@ -18,7 +18,7 @@ arithmetic is accuracy-, not performance-relevant here).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -377,7 +377,8 @@ def _paged_decode_layer(x, p, c, kind, cfg, pos, table, attn_backend,
 
 
 def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, embeds=None, attn_backend: str = "xla",
+                      qparams=None, embeds=None,
+                      attn_backend: Optional[str] = None,
                       shard=None):
     """One decode step against the paged block pool (see the dense family's
     ``paged_decode_step`` for the block-table and ``shard`` conventions)."""
@@ -457,7 +458,7 @@ def _paged_verify_layer(x, p, c, kind, cfg, pos, table, attn_backend):
 
 
 def paged_verify_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, attn_backend: str = "xla"):
+                      qparams=None, attn_backend: Optional[str] = None):
     """Speculative-decode verify step (see the dense family's
     ``paged_verify_step`` for the contract): ``tokens`` [slots, Q] int32,
     returns ``(logits [slots, Q, V], cache)`` with ``cache["len"]``
@@ -614,8 +615,7 @@ def paged_prefill(params, tokens, cfg: ModelConfig, cache, slot, block_ids,
 
 
 def _moe_shard_map(x, p, cfg: ModelConfig, mesh, rules):
-    from repro.core import compat
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     batch_ax = rules.mesh_axes("batch", mesh)
@@ -629,9 +629,9 @@ def _moe_shard_map(x, p, cfg: ModelConfig, mesh, rules):
         e_loc = wg_b.shape[0]
         rank = jax.lax.axis_index("model")
         # declare x varying over 'model': each rank contributes a partial
-        # dx, and pvary's transpose is the psum that sums them
-        x_b = compat.pvary(x_b, ("model",))
-        router_b = compat.pvary(router_b, ("model",))
+        # dx, and this cast's transpose is the psum that sums them
+        x_b = jax.lax.pcast(x_b, ("model",), to="varying")
+        router_b = jax.lax.pcast(router_b, ("model",), to="varying")
         logits = jnp.einsum("bsd,de->bse", x_b.astype(jnp.float32),
                             router_b.astype(jnp.float32))
         probs = jax.nn.softmax(logits, -1)

@@ -17,6 +17,7 @@ layout).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -44,23 +45,35 @@ def _fan_in(shape: Tuple[int, ...]) -> int:
     return shape[-2] if len(shape) >= 2 else shape[-1]
 
 
-def init_params(schema, key: jax.Array, dtype=None):
-    """Materialize a schema into a parameter pytree."""
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _make_leaf(spec: TensorSpec, k, dtype):
+    dt = dtype or spec.dtype
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dt)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, dt)
+    std = spec.scale if spec.scale is not None else _fan_in(spec.shape) ** -0.5
+    if spec.init == "embed":
+        std = spec.scale if spec.scale is not None else 1.0
+    return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dt)
+
+
+def init_params(schema, key: jax.Array, dtype=None, sharding=None):
+    """Materialize a schema into a parameter pytree (``dtype`` overrides
+    every spec's own). Each leaf is drawn by one jitted program, so only
+    the leaf in its final dtype becomes resident — not an f32 draw and
+    its scaled copy beside it. With ``sharding`` each leaf is placed as
+    soon as it is drawn, so the default device never holds the whole
+    tree twice."""
     leaves, treedef = jax.tree.flatten(schema, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
 
-    def make(spec: TensorSpec, k):
-        dt = dtype or spec.dtype
-        if spec.init == "zeros":
-            return jnp.zeros(spec.shape, dt)
-        if spec.init == "ones":
-            return jnp.ones(spec.shape, dt)
-        std = spec.scale if spec.scale is not None else _fan_in(spec.shape) ** -0.5
-        if spec.init == "embed":
-            std = spec.scale if spec.scale is not None else 1.0
-        return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dt)
+    def make(spec, k):
+        leaf = _make_leaf(spec, k, dtype)
+        return leaf if sharding is None else jax.device_put(leaf, sharding)
 
-    return jax.tree.unflatten(treedef, [make(s, k) for s, k in zip(leaves, keys)])
+    return jax.tree.unflatten(
+        treedef, [make(s, k) for s, k in zip(leaves, keys)])
 
 
 def abstract_params(schema, dtype=None):

@@ -11,7 +11,7 @@ this arch runs the ``long_500k`` cell.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +80,7 @@ def _conv1d(xbc, w, b):
     return out + b[None, None, :]
 
 
-def _ssd_block(x, p, cfg: ModelConfig, backend: str = "xla",
+def _ssd_block(x, p, cfg: ModelConfig, backend: Optional[str] = None,
                return_state: bool = False):
     """[B, S, D] → [B, S, D] through one SSD mixing block."""
     b, s, _ = x.shape

@@ -238,12 +238,13 @@ def _decode_layer(x, p, c, kind, cfg: ModelConfig, pos, *, qparams=None):
 
 
 def _qlin(qp_slice, name, y):
-    """Quantized linear for the int8 serving path (static activation scale)."""
+    """Quantized linear for the int8 serving path (static activation scale;
+    the GEMM backend comes from the platform)."""
     from repro.kernels.int8_gemm.ops import int8_gemm
 
     s_in = attn.ACT_SCALE
     y8 = jnp.clip(jnp.round(y.astype(jnp.float32) / s_in), -127, 127).astype(jnp.int8)
-    out8 = int8_gemm(y8, qp_slice[name], backend="xla")
+    out8 = int8_gemm(y8, qp_slice[name])
     return (out8.astype(jnp.float32) * attn.ACT_SCALE).astype(y.dtype)
 
 
@@ -391,7 +392,8 @@ def _paged_cache_write(c, k_new, v_new, pos, table, block_len: int,
 
 
 def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
-                        qparams=None, attn_backend: str = "xla", shard=None):
+                        qparams=None, attn_backend: Optional[str] = None,
+                        shard=None):
     """One-token decode through one layer against the paged pool.
 
     Int8 block pools (``c["k"].dtype == int8``) take the fused quantized
@@ -457,7 +459,8 @@ def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
 
 
 def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, embeds=None, attn_backend: str = "xla",
+                      qparams=None, embeds=None,
+                      attn_backend: Optional[str] = None,
                       shard=None):
     """One decode step against the paged block pool.
 
@@ -545,7 +548,7 @@ def _paged_verify_write(c, k_new, v_new, pos, table, block_len: int,
 
 
 def _paged_verify_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
-                        qparams=None, attn_backend: str = "xla"):
+                        qparams=None, attn_backend: Optional[str] = None):
     """Small-q (speculative verify) pass through one layer: ``x`` carries
     Q = spec_tokens + 1 positions per row — the last committed token plus
     the drafts — all written and scored in one pool sweep. Query row 0
@@ -600,7 +603,7 @@ def _paged_verify_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
 
 
 def paged_verify_step(params, cache, tokens, cfg: ModelConfig, table, *,
-                      qparams=None, attn_backend: str = "xla"):
+                      qparams=None, attn_backend: Optional[str] = None):
     """Speculative-decode verify step: score Q = spec_tokens + 1 positions
     per slot in one dispatch against the paged pool.
 

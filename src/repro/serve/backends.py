@@ -113,10 +113,18 @@ def sample_tokens_per_slot(logits: jax.Array, temps: jax.Array,
     return jnp.where(temps > 0, sampled, greedy_tok)
 
 
+# device memory a fitted pool leaves free for what a step needs beside
+# it: the f32 copy of the unembedding table and the logits in decode, the
+# activations of one prefill bucket (PagedBackend._fit_num_blocks)
+POOL_RESERVE_BYTES = 1 << 30
+
+
 def _build_qparams(arch: registry.Arch, params):
+    # under jit: eagerly, each layer stack's f32 upcast and its
+    # intermediates would sit on the device beside the params
     if arch.cfg.serve_quant and arch.quantize_params is not None and (
             arch.cfg.family in ("dense", "vlm-dense")):
-        return arch.quantize_params(params)
+        return jax.jit(arch.quantize_params)(params)
     return None
 
 
@@ -375,7 +383,7 @@ class SlotBackend(_BackendBase):
         self.caches[slot] = None
 
 
-def validate_paged_config(arch: registry.Arch, attn_backend: str = "xla"):
+def validate_paged_config(arch: registry.Arch, attn_backend: str):
     """Config validation for the paged backend. After ring blocks + paged
     prefill, every attention-cache family serves on the paged path for any
     ``local_window``; what remains unsupported is recurrent state (no
@@ -485,10 +493,9 @@ class PagedBackend(_BackendBase):
                  mesh=None):
         super().__init__(arch, params, ec)
         cfg = arch.cfg
-        from repro.kernels.paged_attention import ops as paged_ops
+        from repro.kernels import resolve_backend
 
-        self.attn_backend = (paged_ops.DEFAULT_BACKEND
-                             if ec.attn_backend is None else ec.attn_backend)
+        self.attn_backend = resolve_backend(ec.attn_backend)
         validate_paged_config(arch, self.attn_backend)
         # -- mesh resolution ------------------------------------------------
         # mesh=None is the single-device path (unchanged). With a mesh the
@@ -519,19 +526,22 @@ class PagedBackend(_BackendBase):
             self.ndev = sizes["model"]
             rules, self.kv_mode = pick_paged_serve_rules(
                 cfg, mesh, kv_shard=ec.kv_shard)
+        # ring blocks when sliding-window layers can't hold full history
+        self.ring = ("L" in cfg.pattern
+                     and cfg.local_window < ec.max_len
+                     and cfg.family != "encdec")
         num_blocks = ec.num_blocks
+        self.pool_sizing: Dict[str, int] = {}
         if num_blocks is None:  # match the dense arena's token budget
             num_blocks = blocks_for(ec.slots * ec.max_len, ec.block_len) + 1
+            if not self.ring:  # ... or what the device's memory holds
+                num_blocks = self._fit_num_blocks(num_blocks)
         if self.kv_mode == "blocks":
             # each device owns an equal slice of the pool (local block 0
             # is that device's trash row); round up so the pool splits
             # evenly and every device keeps at least one usable block
             num_blocks = max(num_blocks, 2 * self.ndev)
             num_blocks = -(-num_blocks // self.ndev) * self.ndev
-        # ring blocks when sliding-window layers can't hold full history
-        self.ring = ("L" in cfg.pattern
-                     and cfg.local_window < ec.max_len
-                     and cfg.family != "encdec")
         wb = ring_blocks_for(cfg.local_window, ec.block_len) if self.ring \
             else 0
         self.layout = PagedLayout(
@@ -616,25 +626,31 @@ class PagedBackend(_BackendBase):
         # quantized archs get int8 block pools (+ per-block scales) — the
         # family default; float archs keep compute_dtype pools
         self.quantized = bool(cfg.serve_quant)
-        self.cache = arch.init_paged_cache(ec.slots, self.layout)
         self.last_tok = jnp.zeros((ec.slots,), jnp.int32)
-        if mesh is not None:
-            # build the cache at global logical shapes, then lay it out on
-            # the mesh per the picked rules; params (and the replicated
-            # host-table uploads each iteration) stay replicated. Keeping
-            # matching out_specs below holds the cache sharded in steady
-            # state with donation intact.
+        if mesh is None:
+            self.cache = arch.init_paged_cache(ec.slots, self.layout)
+        else:
+            # build the cache at global logical shapes straight into its
+            # mesh layout per the picked rules (never whole on one
+            # device: that would need the mesh's pool on one chip);
+            # params (and the replicated host-table uploads each
+            # iteration) stay replicated. Keeping matching out_specs
+            # below holds the cache sharded in steady state with
+            # donation intact.
             from jax.sharding import NamedSharding, PartitionSpec as P
-            from repro.core.compat import shard_map
+            from jax import shard_map
             from repro.models.cache import KVShard
             from repro.parallel.sharding import paged_cache_axes
-            axes = paged_cache_axes(cfg, self.cache, ring=self.ring)
-            self._cache_specs = rules.tree_spec(axes, mesh, like=self.cache)
-            self.cache = jax.device_put(
-                self.cache,
-                jax.tree.map(lambda s: NamedSharding(mesh, s),
-                             self._cache_specs,
-                             is_leaf=lambda x: isinstance(x, P)))
+
+            def init_cache():
+                return arch.init_paged_cache(ec.slots, self.layout)
+
+            shapes = jax.eval_shape(init_cache)
+            axes = paged_cache_axes(cfg, shapes, ring=self.ring)
+            self._cache_specs = rules.tree_spec(axes, mesh, like=shapes)
+            self.cache = jax.jit(init_cache, out_shardings=jax.tree.map(
+                lambda s: NamedSharding(mesh, s), self._cache_specs,
+                is_leaf=lambda x: isinstance(x, P)))()
             rep = NamedSharding(mesh, P())
             self.params = jax.device_put(self.params, rep)
             if self.qparams is not None:
@@ -679,7 +695,7 @@ class PagedBackend(_BackendBase):
             return shard_map(
                 body, mesh=mesh,
                 in_specs=(P(), P(), cache_specs, table_spec, P()),
-                out_specs=(P(), cache_specs), check_rep=False,
+                out_specs=(P(), cache_specs), check_vma=False,
             )(p, qp, cache, table, last_tok)
 
         def _dec(p, qp, cache, table, last_tok, samp, any_sampling):
@@ -717,7 +733,7 @@ class PagedBackend(_BackendBase):
                 body, mesh=mesh,
                 in_specs=(P(), P(), P(), P(), idspec, P(), cache_specs,
                           P(), idspec),
-                out_specs=(P(), cache_specs), check_rep=False,
+                out_specs=(P(), cache_specs), check_vma=False,
             )(p, tokens, true_len, slot, block_ids, ring_ids, cache,
               embeds, prefix_ids)
 
@@ -762,7 +778,7 @@ class PagedBackend(_BackendBase):
             return shard_map(
                 body, mesh=mesh,
                 in_specs=(cache_specs, idspec, idspec),
-                out_specs=cache_specs, check_rep=False)(cache, old, new)
+                out_specs=cache_specs, check_vma=False)(cache, old, new)
 
         self._decode_fn = jax.jit(_dec, donate_argnums=(2,),
                                   static_argnums=(6,))
@@ -1048,14 +1064,21 @@ class PagedBackend(_BackendBase):
         iterations. Steady-state decode mutates no table (growth touches
         one slot every ``block_len`` commits), so re-uploading every
         dispatch is pure host overhead; every mutation site invalidates
-        via ``_touch_tables``."""
+        via ``_touch_tables``.
+
+        The upload is of a snapshot: JAX may read a host array after the
+        call that takes it returns (on the CPU it can alias it), and the
+        host tables are written in place while the dispatch that read
+        them may still be running — uploading ``table`` itself let those
+        writes race into an in-flight step."""
         if self._tables_dev is None:
             if not self.ring:
-                self._tables_dev = jnp.asarray(self.table)
+                self._tables_dev = jnp.asarray(self.table.copy())
             else:
-                self._tables_dev = {"full": jnp.asarray(self.table),
-                                    "ring": jnp.asarray(self.ring_table),
-                                    "start": jnp.asarray(self.ring_start)}
+                self._tables_dev = {
+                    "full": jnp.asarray(self.table.copy()),
+                    "ring": jnp.asarray(self.ring_table.copy()),
+                    "start": jnp.asarray(self.ring_start.copy())}
         return self._tables_dev
 
     def pool_leaves(self):
@@ -1077,6 +1100,46 @@ class PagedBackend(_BackendBase):
         else:
             grab(self.cache)
         return out
+
+    def _fit_num_blocks(self, want: int) -> int:
+        """The pool size for ``num_blocks=None``: ``want`` blocks (what
+        the config addresses), or fewer when the device's memory holds
+        fewer. A device that reports no memory (the CPU) gets ``want``.
+
+        The bytes the params and int8 weights leave free, less
+        ``POOL_RESERVE_BYTES``, are halved: the decode step's scratch
+        holds a second copy of the pool (ROADMAP, Queue 1). A block's
+        bytes on the device are measured by allocating a probe pool, so
+        the device's tiling counts (head_dim 96 pads to 128 lanes on a
+        TPU). With a mesh, each device holds the replicated weights and
+        ``1/ndev`` of every block (heads mode) or of the blocks (blocks
+        mode): the same bytes per device for a pool size. Records the decision in
+        ``pool_sizing``."""
+        # the default device: the probe lands there, and it is the first
+        # device of a serve mesh (launch.mesh.make_serve_mesh)
+        dev = jax.devices()[0]
+        stats = dev.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return want
+        in_use, probe = stats["bytes_in_use"], 64
+        pool = jax.block_until_ready(self.arch.init_paged_cache(
+            self.ec.slots,
+            PagedLayout(self.ec.block_len, probe, self.ec.max_len)))
+        grown = dev.memory_stats()["bytes_in_use"] - in_use
+        del pool
+        block_bytes = max(-(-grown // (probe * self.ndev)), 1)
+        free = stats["bytes_limit"] - in_use - POOL_RESERVE_BYTES
+        fit = max(free, 0) // 2 // block_bytes
+        if fit < 2:
+            raise RuntimeError(
+                f"no room for a KV pool: {in_use} B in use of "
+                f"{stats['bytes_limit']} B, {block_bytes} B per block")
+        self.pool_sizing = dict(
+            num_blocks=int(min(want, fit)), fit_blocks=int(fit),
+            want_blocks=int(want), block_bytes=int(block_bytes),
+            bytes_limit=int(stats["bytes_limit"]),
+            in_use_bytes=int(in_use))
+        return self.pool_sizing["num_blocks"]
 
     @property
     def pool_bytes(self) -> int:
@@ -1186,6 +1249,14 @@ class PagedBackend(_BackendBase):
                         self._ring_ids[i], first)
                     self.ring_start[i] = first * blk
                     self._touch_tables()
+
+    def lower_decode(self, samp, any_sampling: bool = False):
+        """The decode step lowered at the current operands' shapes — what
+        ``decode`` dispatches, for inspecting the program it compiles to
+        (``.compile().as_text()``). Lowering donates nothing."""
+        return self._decode_fn.lower(
+            self.params, self.qparams, self.cache, self._tables(),
+            self.last_tok, samp, any_sampling)
 
     @hot_path
     def decode(self, active, slots, samp, any_sampling):

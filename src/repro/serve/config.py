@@ -55,8 +55,8 @@ class EngineConfig:
     # memory, strictly more admissible requests.
     block_len: int = 16
     num_blocks: Optional[int] = None
-    # paged attention backend (None → kernels.paged_attention default,
-    # env-overridable via REPRO_PAGED_ATTN_BACKEND). Validated at engine
+    # paged attention backend (None → from the platform: the Pallas kernel
+    # on a TPU, the XLA oracle elsewhere). Validated at engine
     # construction: quantized archs must name a backend that implements
     # int8 block pools.
     attn_backend: Optional[str] = None

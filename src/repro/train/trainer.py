@@ -170,7 +170,7 @@ def make_compressed_train_step(arch: registry.Arch, tc: TrainConfig,
     FSDP is future work. Returns (step_fn, init_error_buf_fn); the error
     buffer is part of the training state and must be threaded through.
     """
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
     from repro.optim.grad_compression import compress_decompress_psum
 

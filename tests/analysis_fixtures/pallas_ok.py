@@ -1,6 +1,6 @@
 """pallas-index near misses: kernel idioms that must NOT flag.
 
-Covers: ``pl.dslice`` dynamic stores (the PR-2 fix), constant-index
+Covers: ``pl.ds`` dynamic ref stores (the RG-LRU kernel's form), constant-index
 stores, dynamic *reads* of scalar-prefetch refs (the paged-attention
 idiom), and matching BlockSpec arity.
 """
@@ -28,9 +28,9 @@ def _rglru_kernel_fixed(loga_ref, u_ref, o_ref, h_ref, *, chunk: int):
 
     def step(t, h):
         h = a[t] * h + bu[t]
-        # the PR-2 fix: the dynamic position rides pl.dslice
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[None, None].astype(o_ref.dtype))
+        # the dynamic position rides pl.ds in a ref store
+        o_ref[pl.ds(0, 1), pl.ds(t, 1), :] = h[None, None].astype(
+            o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])

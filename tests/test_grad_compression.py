@@ -10,11 +10,12 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.optim.grad_compression import (
         compress_decompress_psum, init_error_buf)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     local = rng.standard_normal((8, 64, 32)).astype(np.float32)
     grads = {"w": jnp.asarray(local)}

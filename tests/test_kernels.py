@@ -10,8 +10,11 @@ import pytest
 # ---------------------------------------------------------------------------
 
 
+# (8, ...) is a decode batch and (300, ...) a prefill length that no
+# row tile divides: the kernel pads the rows to its fitted row block
 @pytest.mark.parametrize("m,k,n", [(64, 64, 64), (128, 256, 128),
-                                   (256, 512, 256), (64, 1024, 128)])
+                                   (256, 512, 256), (64, 1024, 128),
+                                   (8, 256, 128), (300, 512, 256)])
 @pytest.mark.parametrize("activation", ["none", "relu"])
 def test_int8_gemm_bit_exact(m, k, n, activation):
     from repro.kernels.int8_gemm.ops import QuantizedLinearParams, int8_gemm
