@@ -421,40 +421,48 @@ def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
             "int8 serving over float block pools was removed (the dense-"
             "gather ITA detour): build the paged cache with quantized=True "
             "so K/V live in int8 blocks")
-    h = nn.rms_norm(x, p["ln1"])
     b = x.shape[0]
     hd = cfg.hd
     block_len = c["k"].shape[2]  # [num_blocks, Hkv, block_len, hd]
     lin = functools.partial(_qlin, qparams) if int8_w else (
         lambda name, y: nn.dense(y, p[name]))
-    q = lin("wq", h).reshape(b, 1, cfg.n_heads, hd).transpose(0, 2, 1, 3)
-    k = lin("wk", h).reshape(b, 1, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    v = lin("wv", h).reshape(b, 1, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    q = nn.rope(q, pos[:, None, None], cfg.rope_theta)
-    k = nn.rope(k, pos[:, None, None], cfg.rope_theta)
-    q, k, v = kv_shard_slice(shard, q, k, v)
+    with jax.named_scope("attention"):
+        h = nn.rms_norm(x, p["ln1"])
+        q = lin("wq", h).reshape(b, 1, cfg.n_heads, hd).transpose(0, 2, 1, 3)
+        k = lin("wk", h).reshape(b, 1, cfg.n_kv_heads, hd).transpose(
+            0, 2, 1, 3)
+        v = lin("wv", h).reshape(b, 1, cfg.n_kv_heads, hd).transpose(
+            0, 2, 1, 3)
+        q = nn.rope(q, pos[:, None, None], cfg.rope_theta)
+        k = nn.rope(k, pos[:, None, None], cfg.rope_theta)
+        q, k, v = kv_shard_slice(shard, q, k, v)
 
     window = cfg.local_window if kind == "L" else None
     tbl, start = _resolve_paged_table(table, kind)
-    if int8_kv:
-        c = _paged_cache_write(c, quantize_kv(k, attn.KV_SCALE),
-                               quantize_kv(v, attn.KV_SCALE), pos, tbl,
-                               block_len, start=start)
-        o = paged_attention_int8(q, c["k"], c["v"], tbl, pos + 1,
-                                 k_scale=c["kscale"], v_scale=c["vscale"],
-                                 window=window, start=start,
-                                 backend=attn_backend)
-    else:
-        c = _paged_cache_write(c, k, v, pos, tbl, block_len, start=start)
-        o = paged_attention(q, c["k"], c["v"], tbl, pos + 1,
-                            window=window, start=start,
-                            backend=attn_backend)
-    o = kv_shard_allgather(shard, o)
-    o = kv_shard_owner_rows(shard, o)
-    x = x + lin("wo", _merge_heads(o))
-    h = nn.rms_norm(x, p["ln2"])
-    act = nn.ACTIVATIONS[cfg.act]
-    x = x + lin("wd", act(lin("wg", h), lin("wu", h)))
+    with jax.named_scope("kv_write"):
+        if int8_kv:
+            c = _paged_cache_write(c, quantize_kv(k, attn.KV_SCALE),
+                                   quantize_kv(v, attn.KV_SCALE), pos, tbl,
+                                   block_len, start=start)
+        else:
+            c = _paged_cache_write(c, k, v, pos, tbl, block_len, start=start)
+    with jax.named_scope("attention"):
+        if int8_kv:
+            o = paged_attention_int8(q, c["k"], c["v"], tbl, pos + 1,
+                                     k_scale=c["kscale"],
+                                     v_scale=c["vscale"], window=window,
+                                     start=start, backend=attn_backend)
+        else:
+            o = paged_attention(q, c["k"], c["v"], tbl, pos + 1,
+                                window=window, start=start,
+                                backend=attn_backend)
+        o = kv_shard_allgather(shard, o)
+        o = kv_shard_owner_rows(shard, o)
+        x = x + lin("wo", _merge_heads(o))
+    with jax.named_scope("mlp"):
+        h = nn.rms_norm(x, p["ln2"])
+        act = nn.ACTIVATIONS[cfg.act]
+        x = x + lin("wd", act(lin("wg", h), lin("wu", h)))
     return x, c
 
 
@@ -510,9 +518,11 @@ def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
                                    shard=shard)
         cache["tail"][i] = jax.tree.map(lambda a: a[None], c)
 
-    x = nn.rms_norm(x, params["final_norm"])
-    table_w = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    logits = nn.unembed(x, table_w)
+    with jax.named_scope("unembed"):
+        x = nn.rms_norm(x, params["final_norm"])
+        table_w = (params["embed"] if cfg.tie_embeddings
+                   else params["unembed"])
+        logits = nn.unembed(x, table_w)
     cache = dict(cache, len=cache["len"] + 1)
     return logits[:, 0], cache
 
@@ -567,38 +577,48 @@ def _paged_verify_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
             "int8 serving over float block pools was removed (the dense-"
             "gather ITA detour): build the paged cache with quantized=True "
             "so K/V live in int8 blocks")
-    h = nn.rms_norm(x, p["ln1"])
     b, qlen = x.shape[:2]
     hd = cfg.hd
     block_len = c["k"].shape[2]
     lin = functools.partial(_qlin, qparams) if int8_w else (
         lambda name, y: nn.dense(y, p[name]))
-    q = lin("wq", h).reshape(b, qlen, cfg.n_heads, hd).transpose(0, 2, 1, 3)
-    k = lin("wk", h).reshape(b, qlen, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    v = lin("wv", h).reshape(b, qlen, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    positions = pos[:, None] + jnp.arange(qlen, dtype=jnp.int32)[None, :]
-    q = nn.rope(q, positions[:, None, :], cfg.rope_theta)
-    k = nn.rope(k, positions[:, None, :], cfg.rope_theta)
+    with jax.named_scope("attention"):
+        h = nn.rms_norm(x, p["ln1"])
+        q = lin("wq", h).reshape(b, qlen, cfg.n_heads, hd).transpose(
+            0, 2, 1, 3)
+        k = lin("wk", h).reshape(b, qlen, cfg.n_kv_heads, hd).transpose(
+            0, 2, 1, 3)
+        v = lin("wv", h).reshape(b, qlen, cfg.n_kv_heads, hd).transpose(
+            0, 2, 1, 3)
+        positions = pos[:, None] + jnp.arange(qlen, dtype=jnp.int32)[None, :]
+        q = nn.rope(q, positions[:, None, :], cfg.rope_theta)
+        k = nn.rope(k, positions[:, None, :], cfg.rope_theta)
 
     window = cfg.local_window if kind == "L" else None
     tbl, start = _resolve_paged_table(table, kind)
-    if int8_kv:
-        c = _paged_verify_write(c, quantize_kv(k, attn.KV_SCALE),
-                                quantize_kv(v, attn.KV_SCALE), pos, tbl,
-                                block_len, start=start)
-        o = paged_attention_verify_int8(
-            q, c["k"], c["v"], tbl, pos + 1,
-            k_scale=c["kscale"], v_scale=c["vscale"],
-            window=window, start=start, backend=attn_backend)
-    else:
-        c = _paged_verify_write(c, k, v, pos, tbl, block_len, start=start)
-        o = paged_attention_verify(q, c["k"], c["v"], tbl, pos + 1,
-                                   window=window, start=start,
-                                   backend=attn_backend)
-    x = x + lin("wo", _merge_heads(o))
-    h = nn.rms_norm(x, p["ln2"])
-    act = nn.ACTIVATIONS[cfg.act]
-    x = x + lin("wd", act(lin("wg", h), lin("wu", h)))
+    with jax.named_scope("kv_write"):
+        if int8_kv:
+            c = _paged_verify_write(c, quantize_kv(k, attn.KV_SCALE),
+                                    quantize_kv(v, attn.KV_SCALE), pos, tbl,
+                                    block_len, start=start)
+        else:
+            c = _paged_verify_write(c, k, v, pos, tbl, block_len,
+                                    start=start)
+    with jax.named_scope("attention"):
+        if int8_kv:
+            o = paged_attention_verify_int8(
+                q, c["k"], c["v"], tbl, pos + 1,
+                k_scale=c["kscale"], v_scale=c["vscale"],
+                window=window, start=start, backend=attn_backend)
+        else:
+            o = paged_attention_verify(q, c["k"], c["v"], tbl, pos + 1,
+                                       window=window, start=start,
+                                       backend=attn_backend)
+        x = x + lin("wo", _merge_heads(o))
+    with jax.named_scope("mlp"):
+        h = nn.rms_norm(x, p["ln2"])
+        act = nn.ACTIVATIONS[cfg.act]
+        x = x + lin("wd", act(lin("wg", h), lin("wu", h)))
     return x, c
 
 
@@ -657,9 +677,12 @@ def paged_verify_step(params, cache, tokens, cfg: ModelConfig, table, *,
                                    qparams=qp, attn_backend=attn_backend)
         cache["tail"][i] = jax.tree.map(lambda a: a[None], c)
 
-    x = nn.rms_norm(x, params["final_norm"])
-    table_w = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    return nn.unembed(x, table_w), cache
+    with jax.named_scope("unembed"):
+        x = nn.rms_norm(x, params["final_norm"])
+        table_w = (params["embed"] if cfg.tie_embeddings
+                   else params["unembed"])
+        logits = nn.unembed(x, table_w)
+    return logits, cache
 
 
 def paged_insert(cache, single, slot, block_ids, cfg: ModelConfig):
@@ -761,6 +784,7 @@ def _paged_prefill_impl(params, tokens, cfg: ModelConfig, cache, slot,
     slot = jnp.asarray(slot, jnp.int32)
     n = jnp.asarray(start + s if true_len is None else true_len, jnp.int32)
 
+    @jax.named_scope("kv_write")
     def write(c_kv, k, v, kind):
         if c_kv["k"].dtype == jnp.int8:
             k = quantize_kv(k, attn.KV_SCALE)
@@ -808,11 +832,14 @@ def _paged_prefill_impl(params, tokens, cfg: ModelConfig, cache, slot,
         cache["tail"][i] = jax.tree.map(
             lambda a: a[None], write(c_in, k, v, kind))
 
-    x = nn.rms_norm(x, params["final_norm"])
-    table_w = params["embed"] if cfg.tie_embeddings else params["unembed"]
-    lens = jnp.broadcast_to(n, (b,))
-    last = x[jnp.arange(b), lens - 1 - start][:, None]  # last *real* position
-    logits = nn.unembed(last, table_w)
+    with jax.named_scope("unembed"):
+        x = nn.rms_norm(x, params["final_norm"])
+        table_w = (params["embed"] if cfg.tie_embeddings
+                   else params["unembed"])
+        lens = jnp.broadcast_to(n, (b,))
+        # the last *real* position
+        last = x[jnp.arange(b), lens - 1 - start][:, None]
+        logits = nn.unembed(last, table_w)
     new_len = jax.lax.dynamic_update_slice(
         cache["len"], n[None].astype(jnp.int32), (slot,))
     return logits[:, 0], dict(cache, len=new_len)
@@ -849,25 +876,27 @@ def _prefill_layer(xc, p, kind: str, cfg: ModelConfig, positions, *,
     local-head slice the caller writes into its local pool leaf. Blocks
     mode needs no hook here — prefill math is replicated and the write
     path diverts non-owner ranks to their trash block."""
-    h = nn.rms_norm(xc, p["ln1"])
-    q, k, v = _project_qkv(h, p, cfg, positions)
     from repro.models.cache import kv_shard_allgather, kv_shard_slice
-    q, k, v = kv_shard_slice(shard, q, k, v)
-    ka, va, q_off = k, v, 0
-    if kv_prefix is not None:
-        kp, vp = kv_prefix
-        ka = jnp.concatenate([kp.astype(k.dtype), k], axis=2)
-        va = jnp.concatenate([vp.astype(v.dtype), v], axis=2)
-        q_off = kp.shape[2]
-    o = attn.chunked_attention(
-        q, ka, va, causal=kind != "B",
-        window=cfg.local_window if kind == "L" else None,
-        chunk_q=min(cfg.attn_chunk_q, xc.shape[1]),
-        q_offset=q_off,
-    )
-    o = kv_shard_allgather(shard, o)
-    xc = xc + nn.dense(_merge_heads(o), p["wo"])
-    xc = xc + _mlp(nn.rms_norm(xc, p["ln2"]), p, cfg)
+    with jax.named_scope("attention"):
+        h = nn.rms_norm(xc, p["ln1"])
+        q, k, v = _project_qkv(h, p, cfg, positions)
+        q, k, v = kv_shard_slice(shard, q, k, v)
+        ka, va, q_off = k, v, 0
+        if kv_prefix is not None:
+            kp, vp = kv_prefix
+            ka = jnp.concatenate([kp.astype(k.dtype), k], axis=2)
+            va = jnp.concatenate([vp.astype(v.dtype), v], axis=2)
+            q_off = kp.shape[2]
+        o = attn.chunked_attention(
+            q, ka, va, causal=kind != "B",
+            window=cfg.local_window if kind == "L" else None,
+            chunk_q=min(cfg.attn_chunk_q, xc.shape[1]),
+            q_offset=q_off,
+        )
+        o = kv_shard_allgather(shard, o)
+        xc = xc + nn.dense(_merge_heads(o), p["wo"])
+    with jax.named_scope("mlp"):
+        xc = xc + _mlp(nn.rms_norm(xc, p["ln2"]), p, cfg)
     return xc, k, v
 
 

@@ -30,6 +30,7 @@ shims over this class and stay token-identical to it.
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional, Union
@@ -40,6 +41,7 @@ import numpy as np
 
 from repro.analysis.annotations import hot_path
 from repro.models import registry
+from repro.serve import tracing
 from repro.serve.backends import make_backend
 from repro.serve.config import EngineConfig
 from repro.serve.request import (
@@ -48,6 +50,22 @@ from repro.serve.request import (
 from repro.serve.scheduler import QOS_CLASSES, Scheduler, make_scheduler
 
 Handle = int
+
+#: iterations whose wall time and committed tokens ``metrics()`` keeps (a
+#: rolling window: a long-running server keeps no more than this)
+ITER_WINDOW = 2048
+
+
+def _sampling_span(build):
+    """Run a sampling-vector builder inside an ``engine.sampling`` span
+    that records whether any row samples."""
+    @functools.wraps(build)
+    def traced(self, *args):
+        with tracing.span(tracing.ENGINE_SAMPLING) as sp:
+            vecs, any_sampling = build(self, *args)
+            sp.set_metadata(sampling=int(any_sampling))
+        return vecs, any_sampling
+    return traced
 
 
 class LLMEngine:
@@ -118,13 +136,13 @@ class LLMEngine:
                       else 0)
         self.spec_drafted = 0    # draft tokens proposed across iterations
         self.spec_accepted = 0   # drafts accepted (excludes bonus tokens)
-        # per-iteration wall clock (bounded window): decode-iteration
-        # jitter = p99 − p50 over this window, the number chunked prefill
-        # exists to bound. _iter_tokens rides alongside (same window):
-        # committed tokens per iteration, so walls can be normalized
-        # per-token — a speculative iteration commits several.
-        self._iter_walls: deque = deque(maxlen=2048)
-        self._iter_tokens: deque = deque(maxlen=2048)
+        # per-iteration wall clock over the last ITER_WINDOW iterations:
+        # decode-iteration jitter = p99 − p50 over this window, the number
+        # chunked prefill exists to bound. _iter_tokens rides alongside
+        # (same window): committed tokens per iteration, so walls can be
+        # normalized per-token — a speculative iteration commits several.
+        self._iter_walls: deque = deque(maxlen=ITER_WINDOW)
+        self._iter_tokens: deque = deque(maxlen=ITER_WINDOW)
         # all-greedy dispatches ignore the sampling operands entirely
         # (static any_sampling=False compiles to argmax), so one cached
         # zero vector set per length replaces four host→device uploads
@@ -267,6 +285,7 @@ class LLMEngine:
         """Effective decode temperature (``ec.effective_temperature``)."""
         return self.ec.effective_temperature(req.temperature)
 
+    @_sampling_span
     def _sampling_vectors(self):
         """(per-slot (temps, topks, rids, steps), any_sampling) for this
         iteration's decode dispatch. Empty slots sample greedily into
@@ -309,6 +328,7 @@ class LLMEngine:
             self._greedy_vecs[n] = vecs
         return vecs
 
+    @_sampling_span
     def _admission_vectors(self, req: Request):
         """(length-1 sampling vectors, any_sampling) for an admission
         prefill's first token (same stateless coordinates as decode)."""
@@ -352,6 +372,7 @@ class LLMEngine:
             self.spec_drafted += len(d)
         return mat, spans, drafts
 
+    @_sampling_span
     def _verify_sampling_vectors(self):
         """Flat [slots · (k+1)] sampling vectors for a verify dispatch:
         entry ``i·Q + j`` carries slot ``i``'s coordinates with ``steps``
@@ -387,20 +408,26 @@ class LLMEngine:
         first chunk (within ``budget`` tokens) is dispatched and the
         request stays in PREFILL until later iterations finish it.
         Returns ``(tokens_consumed, tok_or_None)``."""
-        req.state = RequestState.PREFILL
-        req.waiting_iters = 0
-        if self.backend.vectorized:
-            samp, any_sampling = self._admission_vectors(req)
-        else:
-            samp, any_sampling = None, False
-        if self._chunked:
-            self.backend.prefill_begin(req, slot)
+        now = time.perf_counter()
+        if req.admitted_at is None:
+            req.admitted_at = now
+        with tracing.span(tracing.ENGINE_ADMIT, rid=req.rid,
+                          prompt_tokens=len(req.prompt),
+                          waited_us=int((now - req.submitted_at) * 1e6)):
+            req.state = RequestState.PREFILL
+            req.waiting_iters = 0
+            if self.backend.vectorized:
+                samp, any_sampling = self._admission_vectors(req)
+            else:
+                samp, any_sampling = None, False
+            if self._chunked:
+                self.backend.prefill_begin(req, slot)
+                self.slots[slot] = req
+                return self.backend.prefill_chunk(req, slot, budget, samp,
+                                                  any_sampling)
+            tok = self.backend.prefill(req, slot, samp, any_sampling)
             self.slots[slot] = req
-            return self.backend.prefill_chunk(req, slot, budget, samp,
-                                              any_sampling)
-        tok = self.backend.prefill(req, slot, samp, any_sampling)
-        self.slots[slot] = req
-        return 0, tok
+            return 0, tok
 
     def step(self) -> List[StepOutput]:
         """One engine iteration → every request's progress this step."""
@@ -431,6 +458,35 @@ class LLMEngine:
                   if r is not None and r.state == RequestState.RUNNING]
         chunking = [i for i, r in enumerate(self.slots)
                     if r is not None and r.state == RequestState.PREFILL]
+        with tracing.span(tracing.ENGINE_STEP, iteration=self.iterations,
+                          running=len(active), chunking=len(chunking),
+                          queued=len(self.queue)) as sp:
+            if tracing.enabled():
+                sp.set_metadata(written_tokens=self._written_tokens(active),
+                                reserved_blocks=self._reserved_blocks())
+            finished = self._iterate(active, chunking, outputs)
+        self._iter_walls.append(time.perf_counter() - it_t0)
+        self._iter_tokens.append(
+            sum(1 for o in outputs if o.token is not None))
+        return outputs, finished
+
+    def _written_tokens(self, active) -> int:
+        """Sequence lengths of the decode rows ``active``."""
+        return sum(len(self.slots[i].prompt) + len(self.slots[i].output)
+                   for i in active)
+
+    def _reserved_blocks(self) -> int:
+        """Pool blocks held or reserved by admitted requests (0 on a
+        backend without a block pool)."""
+        fn = getattr(self.backend, "reserved_blocks", None)
+        return fn() if fn is not None else 0
+
+    @hot_path
+    def _iterate(self, active, chunking, outputs) -> List[Request]:
+        """The body of ``_step``: decode (or verify) dispatch, chunk
+        continuations, admissions, then the fetch. Appends this
+        iteration's outputs to ``outputs``; returns the requests that
+        finished."""
         at_dispatch = list(self.slots)  # snapshot: who owns each decode row
         self.max_concurrent = max(self.max_concurrent,
                                   len(active) + len(chunking))
@@ -451,8 +507,9 @@ class LLMEngine:
         if active:
             if spec_drafts is not None:
                 samp, any_sampling = self._verify_sampling_vectors()
-                dec_tok = self.backend.verify(active, self.slots, spec_mat,
-                                              samp, any_sampling)
+                dec_tok = self.backend.verify(
+                    active, self.slots, spec_mat, samp, any_sampling,
+                    drafted=sum(map(len, spec_drafts.values())))
             elif self.backend.vectorized:
                 samp, any_sampling = self._sampling_vectors()
                 dec_tok = self.backend.decode(active, self.slots, samp,
@@ -467,8 +524,6 @@ class LLMEngine:
         # the QoS scheduler drains it into rt chunks before be
         # (chunk_order), realizing "rt prefill outranks be work".
         admitted: List[tuple] = []      # (request, slot, first token)
-        granted: List[Request] = []     # dispatched admissions (for credit)
-        granted_slots: set = set()
         budget = self.ec.prefill_chunk_tokens if self._chunked else None
         if chunking:
             pairs = [(i, self.slots[i]) for i in chunking]
@@ -498,8 +553,30 @@ class LLMEngine:
             self.backend.release(i, self.slots[i])
         pre_released = set(will_free)
         free = [i for i, r in enumerate(self.slots) if r is None]
-        avail = free + will_free
+        with tracing.span(tracing.ENGINE_SCHEDULE) as sp:
+            granted, blocked = self._schedule(free + will_free, budget,
+                                              pre_released, admitted)
+            sp.set_metadata(admitted=len(granted), blocked=int(blocked))
 
+        finished = self._fetch_and_finish(dec_tok, active, at_dispatch,
+                                          admitted, pre_released, outputs,
+                                          spec_drafts)
+        # only *dispatched* admissions accrue scheduler credit (a chunked
+        # admission counts from its first chunk; a deferred forced
+        # admission counts nothing — see Scheduler.note_iteration)
+        self.scheduler.note_iteration(granted, list(self.queue))
+        return finished
+
+    @hot_path
+    def _schedule(self, avail, budget, pre_released, admitted):
+        """Admissions into the slots ``avail`` (free or freeing this
+        iteration) within the chunk ``budget``, then forced admission and
+        preemption. Appends each admission whose first token is in flight
+        to ``admitted``; returns ``(granted, blocked)``: the dispatched
+        admissions, and whether the admission pass stopped on capacity."""
+        granted: List[Request] = []     # dispatched admissions (for credit)
+        granted_slots: set = set()
+        blocked = False
         # scheduler-ordered admissions into free (or freeing) slots; stop
         # at the first capacity-blocked request (head-of-line credit —
         # an exhausted chunk budget blocks the head the same way)
@@ -512,6 +589,7 @@ class LLMEngine:
                 self._chunk_stalls += 1
                 break
             if not self.backend.can_admit(req):
+                blocked = True
                 break
             # the backend picks *which* free slot (block-sharded paged
             # serving pins slots to devices; None = capacity exists but
@@ -519,6 +597,7 @@ class LLMEngine:
             # head-of-line credit as a capacity block)
             slot = self._choose_slot(req, avail)
             if slot is None:
+                blocked = True
                 break
             avail.remove(slot)
             self.queue.remove(req)
@@ -590,18 +669,7 @@ class LLMEngine:
                         granted_slots.add(slot)
                         if tok is not None:
                             admitted.append((forced, slot, tok))
-
-        finished = self._fetch_and_finish(dec_tok, active, at_dispatch,
-                                          admitted, pre_released, outputs,
-                                          spec_drafts)
-        # only *dispatched* admissions accrue scheduler credit (a chunked
-        # admission counts from its first chunk; a deferred forced
-        # admission counts nothing — see Scheduler.note_iteration)
-        self.scheduler.note_iteration(granted, list(self.queue))
-        self._iter_walls.append(time.perf_counter() - it_t0)
-        self._iter_tokens.append(
-            sum(1 for o in outputs if o.token is not None))
-        return outputs, finished
+        return granted, blocked
 
     # -- fetch + host-side finish bookkeeping ------------------------------
 
@@ -656,10 +724,14 @@ class LLMEngine:
                 fetch["adm"] = [tok for _, _, tok in admitted]
             if not fetch:
                 return finished
-            jax.tree.map(lambda a: a.copy_to_host_async(), fetch)
-            # repro: allow(host-sync) -- the contract's single fetch per
-            # iteration (async-started above, batched across the slots)
-            got = jax.device_get(fetch)
+            with tracing.span(tracing.ENGINE_FETCH,
+                              arrays=int(dec_tok is not None)
+                              + len(admitted)):
+                jax.tree.map(lambda a: a.copy_to_host_async(), fetch)
+                # repro: allow(host-sync) -- the contract's single fetch
+                # per iteration (async-started above, batched across the
+                # slots)
+                got = jax.device_get(fetch)
             self.backend.transfers += 1
             dec_vals = got.get("dec")
             adm_vals = got.get("adm", [])
@@ -668,6 +740,24 @@ class LLMEngine:
                 return finished
             dec_vals = dec_tok                     # {slot: host int}
             adm_vals = [tok for _, _, tok in admitted]
+        n_out = len(outputs)
+        with tracing.span(tracing.ENGINE_FINISH) as sp:
+            self._commit_tokens(dec_vals, adm_vals, active, at_dispatch,
+                                admitted, pre_released, outputs,
+                                spec_drafts, finished)
+            sp.set_metadata(finished=len(finished),
+                            tokens=len(outputs) - n_out)
+        return finished
+
+    @hot_path
+    def _commit_tokens(self, dec_vals, adm_vals, active, at_dispatch,
+                       admitted, pre_released, outputs, spec_drafts,
+                       finished) -> None:
+        """Host-side finish bookkeeping on the fetched tokens: commit
+        each row's tokens (greedy acceptance under speculation), check
+        stop sequences, EOS and length, and append one ``StepOutput`` per
+        committed token to ``outputs`` and each finished request to
+        ``finished``."""
         now = time.perf_counter()
         if dec_vals is not None:
             for i in active:
@@ -727,7 +817,6 @@ class LLMEngine:
                 rid=req.rid, token=req.output[-1], state=req.state,
                 finish_reason=req.finish_reason if reason else None,
                 qos=req.qos))
-        return finished
 
     # -- observability -----------------------------------------------------
 
@@ -755,11 +844,11 @@ class LLMEngine:
         # per-committed-token normalized walls: a speculative iteration
         # commits several tokens, so the raw iteration wall overstates
         # its per-token latency — normalize by that iteration's commits
-        # (idle iterations commit 0 and divide by 1). Windows are
-        # appended together; the min() guards a partially-filled pair.
+        # (idle iterations commit 0 and divide by 1). Both windows hold
+        # the same last ITER_WINDOW iterations: _step appends to them
+        # together, so they pair entry by entry.
         toks = np.asarray(self._iter_tokens, np.float64)
-        m = min(walls.size, toks.size)
-        per_tok = (walls[-m:] / np.maximum(toks[-m:], 1.0)) if m else walls
+        per_tok = walls / np.maximum(toks, 1.0)
         tp50 = float(np.percentile(per_tok, 50)) if per_tok.size else 0.0
         tp99 = float(np.percentile(per_tok, 99)) if per_tok.size else 0.0
         drafted = float(self.spec_drafted)

@@ -71,10 +71,12 @@ from repro.models.cache import (
     chain_key, chain_seed, prefix_chain_keys, ring_blocks_for,
     ring_table_row,
 )
+from repro.serve import tracing
 from repro.serve.config import EngineConfig
 from repro.serve.request import Request
 
 
+@jax.named_scope("sample")
 def sample_tokens_per_slot(logits: jax.Array, temps: jax.Array,
                            topks: jax.Array, rids: jax.Array,
                            steps: jax.Array, base_key, *,
@@ -1054,6 +1056,12 @@ class PagedBackend(_BackendBase):
             evicted.append(victim_slot)
         return evicted
 
+    def reserved_blocks(self) -> int:
+        """Pool blocks no new request can take: held by admitted requests
+        or reserved for their worst case."""
+        return sum(a.layout.num_blocks - 1 - a.available_blocks
+                   for a in self._all_allocs())
+
     def _touch_tables(self) -> None:
         """Invalidate the cached device table view (call after any host
         write to ``table``/``ring_table``/``ring_start``)."""
@@ -1072,13 +1080,14 @@ class PagedBackend(_BackendBase):
         them may still be running — uploading ``table`` itself let those
         writes race into an in-flight step."""
         if self._tables_dev is None:
-            if not self.ring:
-                self._tables_dev = jnp.asarray(self.table.copy())
-            else:
-                self._tables_dev = {
-                    "full": jnp.asarray(self.table.copy()),
-                    "ring": jnp.asarray(self.ring_table.copy()),
-                    "start": jnp.asarray(self.ring_start.copy())}
+            with tracing.span(tracing.BACKEND_TABLES, slots=self.ec.slots):
+                if not self.ring:
+                    self._tables_dev = jnp.asarray(self.table.copy())
+                else:
+                    self._tables_dev = {
+                        "full": jnp.asarray(self.table.copy()),
+                        "ring": jnp.asarray(self.ring_table.copy()),
+                        "start": jnp.asarray(self.ring_start.copy())}
         return self._tables_dev
 
     def pool_leaves(self):
@@ -1188,6 +1197,15 @@ class PagedBackend(_BackendBase):
         plain one-position decode. The engine caps each span at the
         request's remaining budget, so growth never outruns the
         admission-time block reservation."""
+        with tracing.span(tracing.BACKEND_BEGIN_ITERATION) as sp:
+            sp.set_metadata(grown=self._prepare_rows(active, slots, spans))
+
+    @hot_path
+    def _prepare_rows(self, active, slots, spans) -> int:
+        """``begin_iteration``'s work on each row of ``active``: block
+        growth, prefix publishing, copy-on-write and ring rotation.
+        Returns the blocks grown."""
+        grown = 0
         blk = self.ec.block_len
         for i in active:
             req = slots[i]
@@ -1204,6 +1222,7 @@ class PagedBackend(_BackendBase):
                     b = alloc.grow(req.rid)
                     self._set_table(i, len(owned), b)
                     owned.append(b)
+                    grown += 1
             if self.prefix_caching:
                 # publish decode blocks as they complete: a preempted (or
                 # shared-prefix) continuation then re-prefills mostly from
@@ -1249,6 +1268,7 @@ class PagedBackend(_BackendBase):
                         self._ring_ids[i], first)
                     self.ring_start[i] = first * blk
                     self._touch_tables()
+        return grown
 
     def lower_decode(self, samp, any_sampling: bool = False):
         """The decode step lowered at the current operands' shapes — what
@@ -1260,9 +1280,10 @@ class PagedBackend(_BackendBase):
 
     @hot_path
     def decode(self, active, slots, samp, any_sampling):
-        tok, self.cache = self._decode_fn(
-            self.params, self.qparams, self.cache,
-            self._tables(), self.last_tok, samp, any_sampling)
+        with tracing.span(tracing.BACKEND_DECODE, rows=len(active)):
+            tok, self.cache = self._decode_fn(
+                self.params, self.qparams, self.cache,
+                self._tables(), self.last_tok, samp, any_sampling)
         self.last_tok = tok
         self.decode_dispatches += 1
         for i in active:
@@ -1270,20 +1291,24 @@ class PagedBackend(_BackendBase):
         return tok
 
     @hot_path
-    def verify(self, active, slots, tokens, samp, any_sampling):
+    def verify(self, active, slots, tokens, samp, any_sampling,
+               drafted: int = 0):
         """One speculative verify dispatch — the decode replacement under
         ``spec_tokens > 0``. ``tokens`` [slots, Q] carries each row's last
         committed token in column 0 and its drafts after; ``samp`` are the
         flat [slots · Q] per-position sampling vectors. Returns the chosen
         tokens [slots, Q] on device (one dispatch, fetched with the batch).
         ``_slot_len`` is *not* advanced here — the engine's acceptance
-        drives :meth:`commit` per slot after the fetch."""
-        packed = np.concatenate(
-            [np.asarray(self._slot_len, np.int32)[:, None],
-             np.asarray(tokens, np.int32)], axis=1)
-        tok, self.cache = self._verify_fn(
-            self.params, self.qparams, self.cache, self._tables(),
-            jnp.asarray(packed), samp, any_sampling)
+        drives :meth:`commit` per slot after the fetch. ``drafted`` (the
+        draft tokens in ``tokens``) is a counter of the dispatch's span."""
+        with tracing.span(tracing.BACKEND_VERIFY, rows=len(active),
+                          drafted=drafted):
+            packed = np.concatenate(
+                [np.asarray(self._slot_len, np.int32)[:, None],
+                 np.asarray(tokens, np.int32)], axis=1)
+            tok, self.cache = self._verify_fn(
+                self.params, self.qparams, self.cache, self._tables(),
+                jnp.asarray(packed), samp, any_sampling)
         self.decode_dispatches += 1
         return tok
 
@@ -1457,13 +1482,17 @@ class PagedBackend(_BackendBase):
             bid_arg = jnp.asarray(suffix_ids)
             prefix_ids = jnp.asarray(block_ids[:pb]) if pb else None
         ring_ids = st["ring_ids"]
-        # start=c is static: one trace per (chunk width, resume depth)
-        tok, self.cache, self.last_tok = self._prefill_fn(
-            self.params, tokens, true_len, jnp.asarray(slot, jnp.int32),
-            bid_arg,
-            None if ring_ids is None else jnp.asarray(ring_ids),
-            self.cache, self.last_tok, samp, embeds, prefix_ids,
-            any_sampling, c)
+        # one span per dispatch: a monolithic prefill is the unbudgeted one
+        name = (tracing.BACKEND_PREFILL if budget is None
+                else tracing.BACKEND_PREFILL_CHUNK)
+        with tracing.span(name, rid=req.rid, tokens=length, width=width):
+            # start=c is static: one trace per (chunk width, resume depth)
+            tok, self.cache, self.last_tok = self._prefill_fn(
+                self.params, tokens, true_len, jnp.asarray(slot, jnp.int32),
+                bid_arg,
+                None if ring_ids is None else jnp.asarray(ring_ids),
+                self.cache, self.last_tok, samp, embeds, prefix_ids,
+                any_sampling, c)
         # monolithic mode runs through this same path as one unbudgeted
         # chunk; only budgeted (chunking-active) dispatches count, so the
         # metric reads 0 on monolithic/ring engines

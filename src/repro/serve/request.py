@@ -86,6 +86,8 @@ class Request:
     stop_sequences: Optional[Sequence[Sequence[int]]] = None
     eos_token: Optional[int] = None
     submitted_at: float = 0.0
+    # first admission (a re-admission after preemption keeps it)
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     done_at: Optional[float] = None
     output: List[int] = dataclasses.field(default_factory=list)
