@@ -28,6 +28,18 @@ callers pass zeros and the two layouts share one kernel.
 Contract: allclose against ``ref.paged_attention_ref`` (same masking; the
 flash accumulation only reorders f32 additions).
 
+**Stacked pools** (``layer``): the decode kernels also read a model's
+whole per-pattern-position stack ``[L, N, Hkv, block_len, D]`` in place.
+``layer`` (an int32 scalar, the decode step's loop index) rides in scalar
+prefetch beside the table and the index maps return ``(layer, table[b,
+i], h, 0, 0)``; grid, block shapes, flash math and the one DMA per table
+entry are those of a single layer's pool, which is read as a stack of
+one. Blocks are DMA'd in the order the TPU stores the pool
+(``block_minor``): where it keeps the block axis minor (``block_len`` a
+multiple of 128, ``D`` not), the kernel takes ``[D, block_len]`` blocks
+and contracts over the other axis, so the pool reaches it as a bitcast
+and is never relaid out.
+
 **Int8 block pools** (``paged_attention_int8_pallas``): the quantized
 serving layout stores K/V blocks as int8 plus per-block scales, so the
 kernel DMAs *half* the bytes per block and dequantizes on the fly — q is
@@ -56,12 +68,14 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(
-    table_ref, lens_ref, start_ref,  # scalar prefetch (SMEM)
+    table_ref, lens_ref, start_ref, layer_ref,  # scalar prefetch (SMEM)
     q_ref, k_ref, v_ref,            # blocks picked by index maps
     o_ref,
     m_ref, l_ref, acc_ref,          # VMEM scratch
     *, block_len: int, group: int, window: Optional[int],
+    transposed: bool,
 ):
+    del layer_ref  # read by the index maps only
     b = pl.program_id(0)
     i = pl.program_id(2)
     nb = pl.num_programs(2)
@@ -81,10 +95,11 @@ def _paged_kernel(
     @pl.when(row0 < length)
     def _block():
         q = q_ref[0, 0].astype(jnp.float32)    # [group, D] (pre-scaled)
-        k = k_ref[0, 0].astype(jnp.float32)    # [block_len, D]
-        v = v_ref[0, 0].astype(jnp.float32)    # [block_len, D]
+        # [block_len, D], or [D, block_len] where the pool is block-minor
+        k = k_ref[0, 0, 0].astype(jnp.float32)
+        v = v_ref[0, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (0 if transposed else 1,)), ((), ())),
             preferred_element_type=jnp.float32)  # [group, block_len]
         pos = row0 + jax.lax.broadcasted_iota(
             jnp.int32, (group, block_len), 1)
@@ -100,7 +115,7 @@ def _paged_kernel(
         p = jnp.exp(s - m_new)                 # [group, block_len]
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -112,21 +127,51 @@ def _paged_kernel(
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
+def block_minor(block_len: int, d: int) -> bool:
+    """Whether a TPU stores a ``[..., block_len, D]`` pool with the block
+    axis minor (physically ``[..., D, block_len]``).
+
+    The TPU's default layout keeps a multiple of 128 lanes minor: a head
+    width short of one (96, 80, 64) behind a block of 128, 256, ... rows
+    is stored transposed, so no lane is padding. A kernel must read the
+    pool in that order: its operands are row-major, and any other view
+    makes the compiler relayout the whole pool before the call.
+    """
+    return d % 128 != 0 and block_len % 128 == 0
+
+
+def _stacked(k_pool, v_pool, layer):
+    """(k, v, layer, transposed): the pools as ``[L, N, Hkv, ., .]``
+    stacks in their stored order, and the layer as a ``[1]`` int32 vector
+    for scalar prefetch. A 4-D pool is a stack of one layer."""
+    if layer is None:
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        layer = 0
+    blk, d = k_pool.shape[-2:]
+    transposed = block_minor(blk, d)
+    if transposed:
+        # a bitcast where the pool already lies block-minor
+        k_pool, v_pool = (jnp.swapaxes(a, -1, -2) for a in (k_pool, v_pool))
+    return (k_pool, v_pool,
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), transposed)
+
+
 @functools.partial(
     jax.jit, static_argnames=("window", "interpret"))
 def paged_attention_pallas(
     q: jax.Array,            # [B, Hq, 1, D] float (post-RoPE)
-    k_pool: jax.Array,       # [N, Hkv, block_len, D]
-    v_pool: jax.Array,       # [N, Hkv, block_len, D]
+    k_pool: jax.Array,       # [N, Hkv, block_len, D], or [L, N, ...]
+    v_pool: jax.Array,       # like k_pool
     block_table: jax.Array,  # [B, M] int32
     lens: jax.Array,         # [B] int32
     *,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
+    layer: Optional[jax.Array] = None,  # int32 scalar: the stack's layer
     interpret: bool = False,
 ) -> jax.Array:
     b, hq, _, d = q.shape
-    n, hkv, blk, _ = k_pool.shape
+    hkv, blk = k_pool.shape[-3], k_pool.shape[-2]
     m = block_table.shape[1]
     group = hq // hkv
     if hq % hkv:
@@ -134,21 +179,27 @@ def paged_attention_pallas(
     qg = (q.astype(jnp.float32) * (d ** -0.5)).reshape(b, hkv, group, d)
     if start is None:
         start = jnp.zeros((b,), jnp.int32)
+    k_pool, v_pool, layer, transposed = _stacked(k_pool, v_pool, layer)
+    kv_block = (1, 1, 1) + k_pool.shape[-2:]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # block table + lens + window start drive index maps and masking
-        num_scalar_prefetch=3,
+        # block table + lens + window start + layer drive index maps and
+        # masking
+        num_scalar_prefetch=4,
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, group, d),
-                         lambda bi, h, i, tbl, ln, st: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk, d),
-                         lambda bi, h, i, tbl, ln, st: (tbl[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, blk, d),
-                         lambda bi, h, i, tbl, ln, st: (tbl[bi, i], h, 0, 0)),
+                         lambda bi, h, i, tbl, ln, st, ly: (bi, h, 0, 0)),
+            pl.BlockSpec(kv_block,
+                         lambda bi, h, i, tbl, ln, st, ly:
+                         (ly[0], tbl[bi, i], h, 0, 0)),
+            pl.BlockSpec(kv_block,
+                         lambda bi, h, i, tbl, ln, st, ly:
+                         (ly[0], tbl[bi, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, group, d), lambda bi, h, i, tbl, ln, st: (bi, h, 0, 0)),
+            (1, 1, group, d),
+            lambda bi, h, i, tbl, ln, st, ly: (bi, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -156,14 +207,15 @@ def paged_attention_pallas(
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, block_len=blk, group=group, window=window)
+        _paged_kernel, block_len=blk, group=group, window=window,
+        transposed=transposed)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), jnp.asarray(lens, jnp.int32),
-      jnp.asarray(start, jnp.int32), qg, k_pool, v_pool)
+      jnp.asarray(start, jnp.int32), layer, qg, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
 
@@ -173,12 +225,14 @@ def paged_attention_pallas(
 
 
 def _paged_int8_kernel(
-    table_ref, lens_ref, start_ref, ks_ref, vs_ref,  # scalar prefetch (SMEM)
+    table_ref, lens_ref, start_ref, layer_ref, ks_ref, vs_ref,  # SMEM
     q_ref, k_ref, v_ref,            # q int8 row, int8 pool blocks
     o_ref,
     m_ref, l_ref, acc_ref,          # VMEM scratch (f32)
     *, block_len: int, group: int, window: Optional[int], q_scale: float,
+    transposed: bool,
 ):
+    del layer_ref  # read by the index maps only
     b = pl.program_id(0)
     i = pl.program_id(2)
     nb = pl.num_programs(2)
@@ -196,12 +250,12 @@ def _paged_int8_kernel(
     @pl.when(row0 < length)
     def _block():
         q8 = q_ref[0, 0]                       # [group, D] int8
-        k8 = k_ref[0, 0]                       # [block_len, D] int8
-        v8 = v_ref[0, 0]                       # [block_len, D] int8
+        k8 = k_ref[0, 0, 0]                    # int8, as in the float kernel
+        v8 = v_ref[0, 0, 0]
         # exact integer score dot (the ITA quantized-operand discipline),
         # dequantized through the static q scale × this block's k scale
         s32 = jax.lax.dot_general(
-            q8, k8, (((1,), (1,)), ((), ())),
+            q8, k8, (((1,), (0 if transposed else 1,)), ((), ())),
             preferred_element_type=jnp.int32)  # [group, block_len]
         s = s32.astype(jnp.float32) * (q_scale * ks_ref[blk_id])
         pos = row0 + jax.lax.broadcasted_iota(
@@ -220,7 +274,8 @@ def _paged_int8_kernel(
         # v dequant folds into the partial product: the scale is constant
         # within a block, so (p · v8)·vs ≡ p · (vs·v8)
         pv = jax.lax.dot_general(
-            p, v8.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v8.astype(jnp.float32),
+            (((1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + pv * vs_ref[blk_id]
         m_ref[...] = m_new
@@ -236,20 +291,21 @@ def _paged_int8_kernel(
     jax.jit, static_argnames=("window", "q_scale", "interpret"))
 def paged_attention_int8_pallas(
     q: jax.Array,            # [B, Hq, 1, D] float (post-RoPE)
-    k_pool: jax.Array,       # [N, Hkv, block_len, D] int8
-    v_pool: jax.Array,       # [N, Hkv, block_len, D] int8
+    k_pool: jax.Array,       # [N, Hkv, block_len, D] int8, or [L, N, ...]
+    v_pool: jax.Array,       # like k_pool
     block_table: jax.Array,  # [B, M] int32
     lens: jax.Array,         # [B] int32
-    k_scale: jax.Array,      # [N] f32 per-block scales
+    k_scale: jax.Array,      # [N] f32 per-block scales (the layer's)
     v_scale: jax.Array,      # [N] f32
     *,
     q_scale: float,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
+    layer: Optional[jax.Array] = None,  # int32 scalar: the stack's layer
     interpret: bool = False,
 ) -> jax.Array:
     b, hq, _, d = q.shape
-    n, hkv, blk, _ = k_pool.shape
+    hkv, blk = k_pool.shape[-3], k_pool.shape[-2]
     m = block_table.shape[1]
     group = hq // hkv
     if hq % hkv:
@@ -262,26 +318,29 @@ def paged_attention_int8_pallas(
     qg = q8.reshape(b, hkv, group, d)
     if start is None:
         start = jnp.zeros((b,), jnp.int32)
+    k_pool, v_pool, layer, transposed = _stacked(k_pool, v_pool, layer)
+    kv_block = (1, 1, 1) + k_pool.shape[-2:]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # table + lens + start + per-block k/v scales: the scales are tiny
-        # ([N] f32) and needed at score/accumulate time, so they ride in
-        # SMEM with the rest of the prefetch set
-        num_scalar_prefetch=5,
+        # table + lens + start + layer + per-block k/v scales: the scales
+        # are tiny ([N] f32) and needed at score/accumulate time, so they
+        # ride in SMEM with the rest of the prefetch set
+        num_scalar_prefetch=6,
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, group, d),
-                         lambda bi, h, i, tbl, ln, st, ks, vs: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk, d),
-                         lambda bi, h, i, tbl, ln, st, ks, vs:
-                         (tbl[bi, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, blk, d),
-                         lambda bi, h, i, tbl, ln, st, ks, vs:
-                         (tbl[bi, i], h, 0, 0)),
+                         lambda bi, h, i, tbl, ln, st, ly, ks, vs:
+                         (bi, h, 0, 0)),
+            pl.BlockSpec(kv_block,
+                         lambda bi, h, i, tbl, ln, st, ly, ks, vs:
+                         (ly[0], tbl[bi, i], h, 0, 0)),
+            pl.BlockSpec(kv_block,
+                         lambda bi, h, i, tbl, ln, st, ly, ks, vs:
+                         (ly[0], tbl[bi, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, group, d),
-            lambda bi, h, i, tbl, ln, st, ks, vs: (bi, h, 0, 0)),
+            lambda bi, h, i, tbl, ln, st, ly, ks, vs: (bi, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -290,15 +349,16 @@ def paged_attention_int8_pallas(
     )
     kernel = functools.partial(
         _paged_int8_kernel, block_len=blk, group=group, window=window,
-        q_scale=q_scale)
+        q_scale=q_scale, transposed=transposed)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), jnp.asarray(lens, jnp.int32),
-      jnp.asarray(start, jnp.int32), jnp.asarray(k_scale, jnp.float32),
-      jnp.asarray(v_scale, jnp.float32), qg, k_pool, v_pool)
+      jnp.asarray(start, jnp.int32), layer,
+      jnp.asarray(k_scale, jnp.float32), jnp.asarray(v_scale, jnp.float32),
+      qg, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
 

@@ -8,6 +8,18 @@ table entry ``(p - start) // block_len``, offset ``p % block_len``).
 tables for sliding-window layers rotate and hand the kernel the window's
 block-aligned start per row; full-history tables leave it at 0.
 
+**Stacked pools** (``layer``): both decode ops also take a model's whole
+per-pattern-position stack, ``[L, N, Hkv, block_len, D]``, with
+``layer`` an int32 scalar (traced: the decode step's layer loop index).
+The kernel then DMAs ``pool[layer, table[b, i], h]`` straight from the
+stack — the layer rides in scalar prefetch beside the table — so the
+step never slices a layer out of the pool, and the pool it updates in
+place is the one the kernel reads. Int8 scales are then ``[L, N]`` and
+the layer's row is taken here (a few hundred bytes). Without ``layer``
+the pools are one layer's ``[N, Hkv, block_len, D]``, as the MoE,
+verify and encoder-decoder paths pass them. The ``xla`` oracle indexes
+the layer out and runs as before.
+
 ``paged_attention_int8`` is the quantized-residency variant: the pools are
 int8 blocks with per-block scales (the serving layout fills the scales with
 the static ``attn.KV_SCALE`` calibration; the arrays exist so per-block
@@ -69,28 +81,39 @@ BACKENDS = ("pallas", "interpret", "xla")
 INT8_BACKENDS = ("pallas", "interpret", "xla")
 
 
+def _check_pools(q, k_pool, layer):
+    if k_pool.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            f"pool of rank {k_pool.ndim}: one layer's pool is [N, Hkv, "
+            f"block_len, D], a stack [L, N, Hkv, block_len, D] with layer")
+    if q.shape[1] % k_pool.shape[-3]:
+        raise ValueError(
+            f"query heads {q.shape[1]} not a multiple of kv heads "
+            f"{k_pool.shape[-3]}")
+
+
 @hot_path
 def paged_attention(
     q: jax.Array,            # [B, Hq, 1, D] float (post-RoPE)
-    k_pool: jax.Array,       # [N, Hkv, block_len, D]
-    v_pool: jax.Array,       # [N, Hkv, block_len, D]
+    k_pool: jax.Array,       # [N, Hkv, block_len, D], or [L, N, ...]
+    v_pool: jax.Array,       # like k_pool
     block_table: jax.Array,  # [B, M] int32 pool indices
     lens: jax.Array,         # [B] int32 valid positions per row
     *,
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
+    layer: Optional[jax.Array] = None,  # int32 scalar: the stack's layer
     backend: Optional[str] = None,
 ) -> jax.Array:
     backend = resolve_backend(backend)
-    if q.shape[1] % k_pool.shape[1]:
-        raise ValueError(
-            f"query heads {q.shape[1]} not a multiple of kv heads "
-            f"{k_pool.shape[1]}")
+    _check_pools(q, k_pool, layer)
     if backend in ("pallas", "interpret"):
         return paged_attention_pallas(
             q, k_pool, v_pool, block_table, lens, window=window, start=start,
-            interpret=backend == "interpret")
+            layer=layer, interpret=backend == "interpret")
     if backend == "xla":
+        if layer is not None:
+            k_pool, v_pool = k_pool[layer], v_pool[layer]
         return paged_attention_ref(
             q, k_pool, v_pool, block_table, lens, window=window, start=start)
     raise ValueError(f"unknown backend {backend!r}")
@@ -99,15 +122,16 @@ def paged_attention(
 @hot_path
 def paged_attention_int8(
     q: jax.Array,            # [B, Hq, 1, D] float (post-RoPE)
-    k_pool: jax.Array,       # [N, Hkv, block_len, D] int8
-    v_pool: jax.Array,       # [N, Hkv, block_len, D] int8
+    k_pool: jax.Array,       # [N, Hkv, block_len, D] int8, or [L, N, ...]
+    v_pool: jax.Array,       # like k_pool
     block_table: jax.Array,  # [B, M] int32 pool indices
     lens: jax.Array,         # [B] int32 valid positions per row
     *,
-    k_scale: Optional[jax.Array] = None,  # [N] f32 per-block (None→KV_SCALE)
-    v_scale: Optional[jax.Array] = None,
+    k_scale: Optional[jax.Array] = None,  # [N] ([L, N] with layer) f32
+    v_scale: Optional[jax.Array] = None,  # per-block (None→KV_SCALE)
     window: Optional[int] = None,
     start: Optional[jax.Array] = None,  # [B] int32 abs position of entry 0
+    layer: Optional[jax.Array] = None,  # int32 scalar: the stack's layer
     backend: Optional[str] = None,
 ) -> jax.Array:
     """Decode attention over int8 block pools (see module docstring).
@@ -121,10 +145,7 @@ def paged_attention_int8(
     ``interpret`` kernel and the dequant oracle.
     """
     backend = resolve_backend(backend)
-    if q.shape[1] % k_pool.shape[1]:
-        raise ValueError(
-            f"query heads {q.shape[1]} not a multiple of kv heads "
-            f"{k_pool.shape[1]}")
+    _check_pools(q, k_pool, layer)
     if k_pool.dtype != jnp.int8 or v_pool.dtype != jnp.int8:
         raise ValueError(
             f"paged_attention_int8 needs int8 pools, got "
@@ -132,17 +153,22 @@ def paged_attention_int8(
             f"paged_attention")
     from repro.models.attention import KV_SCALE, Q_SCALE
 
+    if layer is not None:
+        k_scale, v_scale = (None if a is None else a[layer]
+                            for a in (k_scale, v_scale))
     if backend in ("pallas", "interpret"):
-        n = k_pool.shape[0]
+        n = k_pool.shape[-4]
         if k_scale is None:
             k_scale = jnp.full((n,), KV_SCALE, jnp.float32)
         if v_scale is None:
             v_scale = jnp.full((n,), KV_SCALE, jnp.float32)
         return paged_attention_int8_pallas(
             q, k_pool, v_pool, block_table, lens, k_scale, v_scale,
-            q_scale=Q_SCALE, window=window, start=start,
+            q_scale=Q_SCALE, window=window, start=start, layer=layer,
             interpret=backend == "interpret")
     if backend == "xla":
+        if layer is not None:
+            k_pool, v_pool = k_pool[layer], v_pool[layer]
         # the ITA oracle's fixed-point requant constants are compiled from
         # the static KV_SCALE; a non-uniform scale pool would be silently
         # mis-scaled here. Serving passes the (uniformly KV_SCALE) cache

@@ -372,18 +372,21 @@ def _resolve_paged_table(table, kind: str):
     return table, None
 
 
-def _paged_cache_write(c, k_new, v_new, pos, table, block_len: int,
-                       start=None):
-    """Scatter one token's k/v at per-row position ``pos`` through the
-    block table (ring tables pass ``start``, the absolute position of
+def _token_slots(pos, table, block_len: int, start=None):
+    """([B] pool block, [B] offset) of each row's position ``pos`` through
+    the block table (ring tables pass ``start``, the absolute position of
     table entry 0). Empty rows point at the trash block (table row
     zeros), so their writes are harmless."""
-    rows_b = pos.shape[0]
-    max_blocks = table.shape[1]
     rel = pos if start is None else pos - jnp.asarray(start, jnp.int32)
-    bi = jnp.clip(rel // jnp.int32(block_len), 0, max_blocks - 1)
-    blk_ids = table[jnp.arange(rows_b), bi]        # [B] pool rows
-    off = pos % jnp.int32(block_len)
+    bi = jnp.clip(rel // jnp.int32(block_len), 0, table.shape[1] - 1)
+    return table[jnp.arange(pos.shape[0]), bi], pos % jnp.int32(block_len)
+
+
+def _paged_cache_write(c, k_new, v_new, pos, table, block_len: int,
+                       start=None):
+    """Scatter one token's k/v at per-row position ``pos`` into one
+    layer's pool ``[N, Hkv, block_len, hd]`` (see ``_token_slots``)."""
+    blk_ids, off = _token_slots(pos, table, block_len, start)
     k = c["k"].at[blk_ids, :, off].set(k_new[:, :, 0].astype(c["k"].dtype))
     v = c["v"].at[blk_ids, :, off].set(v_new[:, :, 0].astype(c["v"].dtype))
     # dict(c, ...) keeps the int8 layout's per-block scale pools riding
@@ -391,10 +394,30 @@ def _paged_cache_write(c, k_new, v_new, pos, table, block_len: int,
     return dict(c, k=k, v=v)
 
 
-def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
-                        qparams=None, attn_backend: Optional[str] = None,
+def _paged_stack_write(c, k_new, v_new, layer, pos, table, block_len: int,
+                       start=None):
+    """``_paged_cache_write`` into layer ``layer`` of a stacked pool
+    ``[L, N, Hkv, block_len, hd]``, in place: one update of ``[Hkv, hd]``
+    per row. A scatter would have the compiler relayout the pool around
+    it; each row's update leaves the pool in the order it is stored."""
+    blk_ids, off = _token_slots(pos, table, block_len, start)
+    k, v = c["k"], c["v"]
+    for r in range(pos.shape[0]):
+        at = (layer, blk_ids[r], 0, off[r], 0)
+        k = jax.lax.dynamic_update_slice(
+            k, k_new[r, None, None, :, :1].astype(k.dtype), at)
+        v = jax.lax.dynamic_update_slice(
+            v, v_new[r, None, None, :, :1].astype(v.dtype), at)
+    return dict(c, k=k, v=v)
+
+
+def _paged_decode_layer(x, p, c, layer, kind, cfg: ModelConfig, pos, table,
+                        *, qparams=None, attn_backend: Optional[str] = None,
                         shard=None):
-    """One-token decode through one layer against the paged pool.
+    """One-token decode through layer ``layer`` of a stacked paged pool
+    ``c`` (``[L, N, Hkv, block_len, hd]`` leaves): the token's K/V are
+    written in place at ``[layer, block, :, offset]`` and the kernel reads
+    the layer's blocks where they lie.
 
     Int8 block pools (``c["k"].dtype == int8``) take the fused quantized
     path: requantized K/V written straight into int8 blocks and
@@ -423,7 +446,7 @@ def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
             "so K/V live in int8 blocks")
     b = x.shape[0]
     hd = cfg.hd
-    block_len = c["k"].shape[2]  # [num_blocks, Hkv, block_len, hd]
+    block_len = c["k"].shape[3]  # [L, num_blocks, Hkv, block_len, hd]
     lin = functools.partial(_qlin, qparams) if int8_w else (
         lambda name, y: nn.dense(y, p[name]))
     with jax.named_scope("attention"):
@@ -441,20 +464,20 @@ def _paged_decode_layer(x, p, c, kind, cfg: ModelConfig, pos, table, *,
     tbl, start = _resolve_paged_table(table, kind)
     with jax.named_scope("kv_write"):
         if int8_kv:
-            c = _paged_cache_write(c, quantize_kv(k, attn.KV_SCALE),
-                                   quantize_kv(v, attn.KV_SCALE), pos, tbl,
-                                   block_len, start=start)
-        else:
-            c = _paged_cache_write(c, k, v, pos, tbl, block_len, start=start)
+            k = quantize_kv(k, attn.KV_SCALE)
+            v = quantize_kv(v, attn.KV_SCALE)
+        c = _paged_stack_write(c, k, v, layer, pos, tbl, block_len,
+                               start=start)
     with jax.named_scope("attention"):
         if int8_kv:
             o = paged_attention_int8(q, c["k"], c["v"], tbl, pos + 1,
                                      k_scale=c["kscale"],
                                      v_scale=c["vscale"], window=window,
-                                     start=start, backend=attn_backend)
+                                     start=start, layer=layer,
+                                     backend=attn_backend)
         else:
             o = paged_attention(q, c["k"], c["v"], tbl, pos + 1,
-                                window=window, start=start,
+                                window=window, start=start, layer=layer,
                                 backend=attn_backend)
         o = kv_shard_allgather(shard, o)
         o = kv_shard_owner_rows(shard, o)
@@ -488,35 +511,37 @@ def paged_decode_step(params, cache, tokens, cfg: ModelConfig, table, *,
     pos = _as_positions(cache["len"], x.shape[0])
     table = jax.tree.map(lambda a: jnp.asarray(a, jnp.int32), table)
 
-    def group_body(xc, slices):
-        stacks_slice, cache_slice, q_slice = slices
-        new_caches = []
+    def group_body(carry, slices):
+        # the stacked pools ride in the carry and are updated in place;
+        # only the weights are sliced per layer
+        xc, pools = carry
+        layer, stacks_slice, q_slice = slices
+        pools = list(pools)
         for i, kind in enumerate(pattern):
-            xc, c = _paged_decode_layer(
-                xc, stacks_slice[i], cache_slice[i], kind, cfg, pos, table,
+            xc, pools[i] = _paged_decode_layer(
+                xc, stacks_slice[i], pools[i], layer, kind, cfg, pos, table,
                 qparams=None if q_slice is None else q_slice[i],
                 attn_backend=attn_backend, shard=shard,
             )
-            new_caches.append(c)
-        return xc, tuple(new_caches)
+        return (xc, tuple(pools)), None
 
     if n_groups > 0:
         qstacks = None if qparams is None else tuple(qparams["stacks"])
-        x, new_stack_caches = jax.lax.scan(
-            group_body, x,
-            (tuple(params["stacks"]), tuple(cache["stacks"]), qstacks),
+        (x, pools), _ = jax.lax.scan(
+            group_body, (x, tuple(cache["stacks"])),
+            (jnp.arange(n_groups, dtype=jnp.int32), tuple(params["stacks"]),
+             qstacks),
         )
-        cache = dict(cache, stacks=list(new_stack_caches))
+        cache = dict(cache, stacks=list(pools))
     for i, kind in enumerate(tail):
+        # a tail layer's pools are stacks of one
         p = jax.tree.map(lambda a: a[0], params["tail"][i])
         qp = None
         if qparams is not None:
             qp = jax.tree.map(lambda a: a[0], qparams["tail"][i])
-        c_in = jax.tree.map(lambda a: a[0], cache["tail"][i])
-        x, c = _paged_decode_layer(x, p, c_in, kind, cfg, pos, table,
-                                   qparams=qp, attn_backend=attn_backend,
-                                   shard=shard)
-        cache["tail"][i] = jax.tree.map(lambda a: a[None], c)
+        x, cache["tail"][i] = _paged_decode_layer(
+            x, p, cache["tail"][i], jnp.int32(0), kind, cfg, pos, table,
+            qparams=qp, attn_backend=attn_backend, shard=shard)
 
     with jax.named_scope("unembed"):
         x = nn.rms_norm(x, params["final_norm"])

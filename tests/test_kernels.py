@@ -205,3 +205,156 @@ def test_rglru_decode_matches_scan():
     for t in range(S):
         h, out = rglru_decode_step(h, log_a[:, t], u[:, t])
     np.testing.assert_allclose(np.asarray(out), y[:, -1], rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode attention over a stacked pool (layer-indexed entry)
+# ---------------------------------------------------------------------------
+
+
+def _stacked_case(int8, blk, seed=0):
+    """Pools [L, N, Hkv, blk, D], queries, and a table over blocks 1..N-1
+    (block 0 is the trash block) with an empty row (len 0)."""
+    rng = np.random.default_rng(seed)
+    L, B, HQ, HKV, D, N, M = 3, 3, 8, 2, 16, 7, 3
+    shape = (L, N, HKV, blk, D)
+    if int8:
+        kp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    else:
+        kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, HQ, 1, D)), jnp.float32)
+    tbl = jnp.asarray(rng.permutation(np.arange(1, N))[:B * M].reshape(B, M)
+                      if B * M < N else rng.integers(1, N, (B, M)), jnp.int32)
+    lens = jnp.asarray([blk * M - 1, 0, blk + 1], jnp.int32)
+    return kp, vp, q, tbl, lens
+
+
+# blk 4 keeps the pool row-major; blk 128 with D 16 is stored block-minor
+# on a TPU, so the kernel reads the transposed view
+@pytest.mark.parametrize("blk", [4, 128])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("ring", [False, True], ids=["full", "ring"])
+def test_paged_attention_layer_matches_layer_slice(int8, blk, ring):
+    """The layer-indexed call on the stack equals the 4-D call on
+    ``pool[l]`` for every layer, bit for bit, and the oracle within flash
+    reordering — empty row, ring table with start and window included."""
+    from repro.kernels.paged_attention.kernel import block_minor
+    from repro.kernels.paged_attention.ops import (
+        paged_attention, paged_attention_int8,
+    )
+    from repro.kernels.paged_attention.ref import (
+        paged_attention_int8_dequant_ref, paged_attention_ref,
+    )
+
+    assert block_minor(blk, 16) == (blk == 128)
+    kp, vp, q, tbl, lens = _stacked_case(int8, blk)
+    kw = {}
+    if ring:
+        # rows attend a window of one block and a bit behind their length,
+        # the ring table's entry 0 sitting one block back
+        kw = dict(window=blk + 2,
+                  start=jnp.asarray([blk, 0, 0], jnp.int32))
+        lens = jnp.asarray([blk * 3, 0, blk + 1], jnp.int32)
+    n = kp.shape[1]
+    scale = jnp.stack([jnp.linspace(0.01, 0.03, n, dtype=jnp.float32)] * 3)
+    for layer in range(kp.shape[0]):
+        if int8:
+            stacked = paged_attention_int8(
+                q, kp, vp, tbl, lens, k_scale=scale, v_scale=scale * 2,
+                layer=jnp.int32(layer), backend="interpret", **kw)
+            flat = paged_attention_int8(
+                q, kp[layer], vp[layer], tbl, lens, k_scale=scale[layer],
+                v_scale=scale[layer] * 2, backend="interpret", **kw)
+            ref = paged_attention_int8_dequant_ref(
+                q, kp[layer], vp[layer], tbl, lens, k_scale=scale[layer],
+                v_scale=scale[layer] * 2, **kw)
+        else:
+            stacked = paged_attention(q, kp, vp, tbl, lens,
+                                      layer=jnp.int32(layer),
+                                      backend="interpret", **kw)
+            flat = paged_attention(q, kp[layer], vp[layer], tbl, lens,
+                                   backend="interpret", **kw)
+            ref = paged_attention_ref(q, kp[layer], vp[layer], tbl, lens,
+                                      **kw)
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(flat))
+        np.testing.assert_allclose(np.asarray(stacked), np.asarray(ref),
+                                   atol=3e-6, rtol=3e-5)
+        assert float(jnp.abs(stacked[1]).max()) == 0.0   # the empty row
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_layer_xla_matches_layer_slice(int8):
+    """The ``xla`` oracle takes the same ``layer`` argument."""
+    from repro.kernels.paged_attention.ops import (
+        paged_attention, paged_attention_int8,
+    )
+
+    kp, vp, q, tbl, lens = _stacked_case(int8, 4, seed=1)
+    op = paged_attention_int8 if int8 else paged_attention
+    for layer in range(kp.shape[0]):
+        stacked = op(q, kp, vp, tbl, lens, layer=jnp.int32(layer),
+                     backend="xla")
+        flat = op(q, kp[layer], vp[layer], tbl, lens, backend="xla")
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(flat))
+
+
+def test_paged_attention_rejects_pool_rank_without_layer():
+    from repro.kernels.paged_attention.ops import paged_attention
+
+    kp, vp, q, tbl, lens = _stacked_case(False, 4)
+    with pytest.raises(ValueError, match="rank"):
+        paged_attention(q, kp, vp, tbl, lens, backend="interpret")
+    with pytest.raises(ValueError, match="rank"):
+        paged_attention(q, kp[0], vp[0], tbl, lens, layer=jnp.int32(0),
+                        backend="interpret")
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["full", "ring"])
+def test_paged_stack_write_touches_only_its_tokens(ring):
+    """The in-place decode write puts each row's token at
+    ``[layer, block, :, offset]`` and leaves every other byte of the stack
+    as it was: the other layers (trash block included) whole, and in the
+    written layer every other block and offset. Empty rows (table zeros)
+    write into the layer's trash block only."""
+    from repro.models.cache import TRASH_BLOCK
+    from repro.models.transformer import _paged_stack_write
+
+    rng = np.random.default_rng(4)
+    L, N, HKV, BLK, D, M = 3, 8, 2, 4, 5, 3
+    pools = {n: jnp.asarray(rng.standard_normal((L, N, HKV, BLK, D)),
+                            jnp.bfloat16) for n in ("k", "v")}
+    pools["kscale"] = jnp.ones((L, N), jnp.float32)
+    table = jnp.asarray([[3, 5, 6], [0, 0, 0], [2, 7, 1]], jnp.int32)
+    pos = jnp.asarray([9, 0, 2], jnp.int32)      # the middle row is empty
+    start = None
+    if ring:
+        # ring rows: entry 0 holds absolute block 1 (positions 4..7)
+        start = jnp.asarray([4, 0, 0], jnp.int32)
+    k_new = jnp.asarray(rng.standard_normal((3, HKV, 1, D)), jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((3, HKV, 1, D)), jnp.float32)
+    layer = 1
+    out = jax.jit(_paged_stack_write, static_argnums=(6,))(
+        pools, k_new, v_new, jnp.int32(layer), pos, table, BLK, start)
+    np.testing.assert_array_equal(np.asarray(out["kscale"]),
+                                  np.asarray(pools["kscale"]))
+
+    first = np.zeros(3, np.int64) if start is None else np.asarray(start)
+    for name, new in (("k", k_new), ("v", v_new)):
+        want = np.asarray(pools[name], np.float32).copy()
+        for r in range(3):
+            bi = (int(pos[r]) - int(first[r])) // BLK
+            blk = int(table[r, bi])
+            want[layer, blk, :, int(pos[r]) % BLK] = np.asarray(
+                new[r, :, 0].astype(jnp.bfloat16), np.float32)
+        got = np.asarray(out[name], np.float32)
+        np.testing.assert_array_equal(got, want)
+        # what changed lies in the written layer only, the trash block
+        # of the other layers included
+        changed = np.argwhere(got != np.asarray(pools[name], np.float32))
+        assert set(changed[:, 0].tolist()) <= {layer}
+        for other in set(range(L)) - {layer}:
+            np.testing.assert_array_equal(
+                got[other, TRASH_BLOCK],
+                np.asarray(pools[name], np.float32)[other, TRASH_BLOCK])
